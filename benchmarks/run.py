@@ -15,6 +15,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         compression_bench,
         fig6_ablation,

@@ -29,6 +29,7 @@ from repro.graph import (
     renumber_and_normalize,
     slice_snapshots,
 )
+from repro.launch.cache import use_compile_cache
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--snapshots", type=int, default=24)
     ap.add_argument("--streams", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     pairs = [("evolvegcn", ("v1", "v3")), ("gcrn-m2", ("v2", "v3"))]
     for ds in (BC_ALPHA, UCI):

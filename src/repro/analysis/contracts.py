@@ -217,7 +217,7 @@ def _check_launch(point: Point, launch) -> list:
                     f"{sm.out_idx} — the HBM store would not evolve "
                     "in place"))
         for idx, spec in enumerate(launch.in_specs):
-            is_any = getattr(spec, "memory_space", None) is stream_fused.pltpu.ANY
+            is_any = getattr(spec, "memory_space", None) is stream_fused.pl.ANY
             if is_any and idx not in spec_states and idx not in launch.aliases:
                 out.append(_find(
                     "hbm-alias-coverage",
@@ -260,14 +260,14 @@ def _launch_dims(family: str, launch):
     meta = launch.meta
     out0 = launch.out_shape[0].shape          # (B, T, n_pad, d_pad)
     dims = dict(g_rows=meta.g_rows, n_pad=out0[2], d_pad=out0[3],
-                n_layers=launch.grid[2], din=0, dmid=0)
+                n_layers=launch.grid[2], tn=meta.tn, din=0, dmid=0)
     ins = launch.inputs
-    if family == "gcrn":
-        dims["din"] = ins[4].shape[3]
-    elif family in ("stacked", "tgn"):
-        dims["din"] = ins[3].shape[3]
+    if family in ("gcrn", "stacked"):
+        dims["din"] = ins[2].shape[3]
         if family == "stacked":
-            dims["dmid"] = ins[7].shape[1]
+            dims["dmid"] = ins[6].shape[1]
+    elif family == "tgn":
+        dims["din"] = ins[3].shape[3]
     elif family not in ("evolve", "static_gcn"):
         return None  # unknown family: no estimator formula to check
     return dims

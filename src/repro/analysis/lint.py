@@ -60,6 +60,15 @@ JNP_KERNEL_DENYLIST = frozenset({
     "moveaxis", "append", "delete", "insert", "resize",
 })
 
+#: further ops barred from the stream engine's kernel bodies (the cell
+#: hooks and the ``_Engine`` methods of kernels/stream_fused.py): a gather
+#: or an ``.at[...]`` update over a VMEM value has no Mosaic lowering —
+#: the engine aggregates with ``_ell_matrix`` matmuls and moves global
+#: store rows with scalar-indexed row loops instead.
+STREAM_ENGINE_DENYLIST = frozenset({"take", "take_along_axis"})
+_AT_UPDATES = frozenset({"set", "add", "multiply", "divide", "power",
+                         "min", "max", "apply", "get"})
+
 RULES = {r.id: r for r in (
     Rule("stream-def-outside-registry", "lint", "error",
          "Family code lives in stream_fused.REGISTRY as declarative cell "
@@ -90,7 +99,9 @@ RULES = {r.id: r for r in (
          "Inside a Pallas kernel body, shape-restructuring / "
          "data-dependent jnp ops (einsum, concatenate, sort, cumsum, …) "
          "either fail to lower on TPU or hide a relayout; use lax "
-         "equivalents or static slices on the host side."),
+         "equivalents or static slices on the host side. The stream "
+         "engine's kernel bodies also may not gather (jnp.take) or "
+         "update a value through .at[...]: neither lowers on the chip."),
     Rule("syntax-error", "lint", "error",
          "A file in the lint scope failed to parse — nothing else can be "
          "checked until it does."),
@@ -270,24 +281,55 @@ def _chk_mutable_default(path, tree, lines):
     return out
 
 
+def _engine_methods(tree) -> set:
+    """The ``_Engine`` methods of the stream-engine module: they run
+    inside the kernel on behalf of every cell."""
+    return {id(fn) for cls in ast.walk(tree)
+            if isinstance(cls, ast.ClassDef) and cls.name == "_Engine"
+            for fn in cls.body if isinstance(fn, ast.FunctionDef)}
+
+
+def _at_update(node) -> Optional[str]:
+    """``x.at[...].<op>(...)`` -> op, else None."""
+    fn = node.func
+    if (isinstance(fn, ast.Attribute) and fn.attr in _AT_UPDATES
+            and isinstance(fn.value, ast.Subscript)
+            and isinstance(fn.value.value, ast.Attribute)
+            and fn.value.value.attr == "at"):
+        return fn.attr
+    return None
+
+
 def _chk_jnp_in_kernel(path, tree, lines):
     if not path.startswith("src/repro/kernels/"):
         return []
+    engine = path == STREAM_FUSED
+    methods = _engine_methods(tree) if engine else set()
+    deny = JNP_KERNEL_DENYLIST | (STREAM_ENGINE_DENYLIST if engine
+                                  else frozenset())
     out = []
     for fn in ast.walk(tree):
-        if not isinstance(fn, ast.FunctionDef) or not _is_kernel_body(fn):
+        if not isinstance(fn, ast.FunctionDef) or not (
+                _is_kernel_body(fn) or id(fn) in methods):
             continue
         for node in ast.walk(fn):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
+            if not isinstance(node, ast.Call):
+                continue
+            if (isinstance(node.func, ast.Attribute)
                     and isinstance(node.func.value, ast.Name)
                     and node.func.value.id == "jnp"
-                    and node.func.attr in JNP_KERNEL_DENYLIST):
+                    and node.func.attr in deny):
                 out.append(_find(
                     "jnp-in-kernel-body", path, node,
                     f"jnp.{node.func.attr} inside kernel body "
                     f"`{fn.name}` — no TPU Pallas lowering / hides a "
                     "relayout; use the lax equivalent or hoist host-side"))
+            elif engine and _at_update(node):
+                out.append(_find(
+                    "jnp-in-kernel-body", path, node,
+                    f".at[...].{_at_update(node)} inside kernel body "
+                    f"`{fn.name}` — no TPU Pallas lowering; move rows "
+                    "with the engine's scatter_tile row loop"))
     return out
 
 

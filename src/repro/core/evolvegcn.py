@@ -115,19 +115,13 @@ class EvolveGCN:
         (..., n, din_l) with any leading (T,) / (B, T) axes. The edge
         contribution is additive in the ELL aggregation, so it factors out
         of the kernel (which then only gathers node activations)."""
+        from repro.kernels import ops as kops
+
         if not self.cfg.edge_dim:
             return None
-        eidx = snaps.neigh_eidx
-        lead = eidx.shape[:-2]
-        n, k = eidx.shape[-2:]
-        flat = eidx.reshape(*lead, n * k, 1)
-        aggs = []
-        for p in params["gcn"]:
-            emsg = snaps.edge_feat @ p["w_edge"]     # (..., e, din_l)
-            g = jnp.take_along_axis(emsg, flat, axis=-2)
-            g = g.reshape(*lead, n, k, emsg.shape[-1])
-            aggs.append((g * snaps.neigh_coef[..., None]).sum(axis=-2))
-        return aggs
+        return [kops.edge_aggregate(snaps.neigh_coef, snaps.neigh_eidx,
+                                    snaps.edge_feat @ p["w_edge"])
+                for p in params["gcn"]]
 
     def _run_stream_kernel(self, params: dict, state: dict,
                            snaps: PaddedSnapshot, batched: bool,

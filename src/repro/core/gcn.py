@@ -138,24 +138,16 @@ class StaticGCN:
         """Per-layer pre-aggregated edge-message term (additive in the
         ELL aggregation, so it factors out of the kernel); zero for
         layers without edge weights (only layer 0 projects edges)."""
+        from repro.kernels import ops as kops
+
         if params["gcn"][0].get("w_edge") is None:
             return None
-        eidx = snaps.neigh_eidx
-        lead = eidx.shape[:-2]
-        n, k = eidx.shape[-2:]
-        flat = eidx.reshape(*lead, n * k, 1)
-        aggs = []
-        for p in params["gcn"]:
-            we = p.get("w_edge")
-            if we is None:
-                aggs.append(jnp.zeros((*lead, n, p["w"].shape[0]),
-                                      jnp.float32))
-                continue
-            emsg = snaps.edge_feat @ we
-            g = jnp.take_along_axis(emsg, flat, axis=-2)
-            g = g.reshape(*lead, n, k, emsg.shape[-1])
-            aggs.append((g * snaps.neigh_coef[..., None]).sum(axis=-2))
-        return aggs
+        lead_n = snaps.neigh_eidx.shape[:-1]
+        return [jnp.zeros((*lead_n, p["w"].shape[0]), jnp.float32)
+                if p.get("w_edge") is None
+                else kops.edge_aggregate(snaps.neigh_coef, snaps.neigh_eidx,
+                                         snaps.edge_feat @ p["w_edge"])
+                for p in params["gcn"]]
 
     @staticmethod
     def _check_residency(state_residency, buffer_depth):

@@ -87,8 +87,6 @@ def make_compressed_grad_fn(loss_fn, mesh, batch_axes=("data",),
     Returns grad_fn(params, residuals, batch) -> (grads, new_residuals, loss).
     params replicated; batch sharded on its leading axis over ``batch_axes``.
     """
-    from jax.experimental.shard_map import shard_map
-
     axis = batch_axes[0]
 
     def body(params, residuals, batch):
@@ -112,7 +110,7 @@ def make_compressed_grad_fn(loss_fn, mesh, batch_axes=("data",),
 
     def grad_fn(params, residuals, batch):
         batch_specs = jax.tree.map(lambda _: P(axis), batch)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, params),
                       jax.tree.map(lambda _: rep, residuals),
@@ -120,7 +118,7 @@ def make_compressed_grad_fn(loss_fn, mesh, batch_axes=("data",),
             out_specs=(jax.tree.map(lambda _: rep, params),
                        jax.tree.map(lambda _: rep, residuals),
                        rep),
-            check_rep=False,
+            check_vma=False,
         )(params, residuals, batch)
 
     return grad_fn

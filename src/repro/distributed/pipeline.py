@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -72,11 +71,11 @@ def pipeline(block_fn: Callable, mesh, n_stages: int, n_micro: int,
         mask = (sid == n_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, stage_axis)
 
-    return shard_map(
+    return jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -1,10 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; elsewhere (this CPU container) they
-run in interpret mode, which executes the kernel body in Python with the
-same tiling — the correctness contract tests rely on. ``force_ref=True``
-routes to the pure-jnp oracle (used by the XLA production path when the
-Pallas path is not profitable, e.g. tiny snapshots under vmap).
+On TPU the kernels compile natively (Mosaic). On the CPU backend — and
+only there — they run in interpret mode, which executes the kernel body
+with the same tiling: the correctness contract the CPU tests rely on.
+``force_ref=True`` routes to the pure-jnp oracle (used by the XLA
+production path when the Pallas path is not profitable, e.g. tiny
+snapshots under vmap).
 
 Ragged node counts are handled here: row-tiled inputs are auto-padded to
 the node tile ``tn`` (the sink-row coef-0 convention of graph/padding.py:
@@ -24,7 +25,9 @@ from repro.kernels import stream_fused as _stream
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU backend only: an accelerator never
+    silently runs the interpreter in place of its compiled kernels."""
+    return jax.default_backend() == "cpu"
 
 
 _FORCE_REF = False
@@ -178,20 +181,24 @@ def _pad_stream(neigh_idx, neigh_coef, neigh_eidx, node_feat, renumber,
             _pad_to(renumber, n2, -1, fill=-1), _pad_to(node_mask, n2, -1))
 
 
-def _stream_index_tables(renumber, neigh_idx, n_global: int):
-    """Precompute the kernel's global-id tables from the renumber stream.
+def _row_table(renumber, n_global: int):
+    """Global store row of each local node (``n_global``, the drop
+    sentinel, on padding rows) — the kernel's per-step SMEM row-id table.
+    Leading axes (T,) or (B, T) pass through untouched."""
+    return jnp.where(renumber >= 0, renumber, n_global).astype(jnp.int32)
 
-    ``neigh_gidx``: global id of each ELL lane's source node (safe 0 where
-    the lane is padding — its coef is 0). ``row_gidx``: global row of each
-    local node, ``n_global`` (the drop sentinel) on padding rows. Leading
-    axes (T,) or (B, T) pass through untouched.
-    """
-    ren_safe = jnp.where(renumber >= 0, renumber, 0).astype(jnp.int32)
-    flat = neigh_idx.reshape(*neigh_idx.shape[:-2], -1)
-    neigh_gidx = jnp.take_along_axis(ren_safe, flat,
-                                     axis=-1).reshape(neigh_idx.shape)
-    row_gidx = jnp.where(renumber >= 0, renumber, n_global).astype(jnp.int32)
-    return neigh_gidx.astype(jnp.int32), row_gidx
+
+def edge_aggregate(coef, eidx, edge_msg):
+    """Pre-aggregated ELL edge-message term ``sum_k coef[..., k] *
+    edge_msg[eidx[..., k]]`` of shape (..., n, width): additive in the
+    aggregation, so it factors out of the stream kernel, which then only
+    aggregates node rows. ``edge_msg`` is (..., e, width) with the same
+    leading axes as ``eidx`` (..., n, k)."""
+    lead = eidx.shape[:-2]
+    n, k = eidx.shape[-2:]
+    g = jnp.take_along_axis(edge_msg, eidx.reshape(*lead, n * k, 1), axis=-2)
+    g = g.reshape(*lead, n, k, edge_msg.shape[-1])
+    return (g * coef[..., None]).sum(axis=-2)
 
 
 def _gcrn_launch(batched, neigh_idx, neigh_coef, neigh_eidx, node_feat,
@@ -208,12 +215,12 @@ def _gcrn_launch(batched, neigh_idx, neigh_coef, neigh_eidx, node_feat,
         return outs[0], hT[0], cT[0]
     n, idx, coef, eidx, x, ren, mask = _pad_stream(
         neigh_idx, neigh_coef, neigh_eidx, node_feat, renumber, node_mask, tn)
-    gidx, rowg = _stream_index_tables(ren, idx, h0.shape[1])
+    eagg = None if edge_msg is None else edge_aggregate(coef, eidx, edge_msg)
     h = h0.shape[-1]
     outs, hT, cT = _stream.stream_call(
-        "gcrn", idx, gidx, coef, eidx, x, rowg, mask, h0, c0, wx, wh, b,
-        edge_msg, tn=tn, td=td, interpret=_interpret(), residency=residency,
-        depth=depth)
+        "gcrn", idx, coef, x, _row_table(ren, h0.shape[1]), mask, h0, c0,
+        wx, wh, b, eagg, tn=tn, td=td, interpret=_interpret(),
+        residency=residency, depth=depth)
     return outs[:, :, :n, :h], hT[..., :h], cT[..., :h]
 
 
@@ -232,11 +239,11 @@ def _stacked_launch(batched, neigh_idx, neigh_coef, neigh_eidx, node_feat,
         return outs[0], hT[0]
     n, idx, coef, eidx, x, ren, mask = _pad_stream(
         neigh_idx, neigh_coef, neigh_eidx, node_feat, renumber, node_mask, tn)
-    _, rowg = _stream_index_tables(ren, idx, h0.shape[1])
+    eagg = None if edge_msg is None else edge_aggregate(coef, eidx, edge_msg)
     h = h0.shape[-1]
     outs, hT = _stream.stream_call(
-        "stacked", idx, coef, eidx, x, rowg, mask, h0, w_gcn, b_gcn,
-        wx, wh, b, edge_msg, tn=tn, td=td, interpret=_interpret(),
+        "stacked", idx, coef, x, _row_table(ren, h0.shape[1]), mask, h0,
+        w_gcn, b_gcn, wx, wh, b, eagg, tn=tn, td=td, interpret=_interpret(),
         residency=residency, depth=depth)
     return outs[:, :, :n, :h], hT[..., :h]
 
@@ -366,12 +373,11 @@ def _tgn_launch(batched, neigh_idx, neigh_coef, neigh_ts, node_feat,
     # ts rides the eidx slot of the shared padder (same node-axis layout)
     n, idx, coef, ts, x, ren, mask = _pad_stream(
         neigh_idx, neigh_coef, neigh_ts, node_feat, renumber, node_mask, tn)
-    gidx, rowg = _stream_index_tables(ren, idx, mem0.shape[1])
     h = mem0.shape[-1]
     outs, memT = _stream.stream_call(
-        "tgn", gidx, coef, ts, x, rowg, mask, mem0, freq, w_in, wx, wh, b,
-        tn=tn, td=td, interpret=_interpret(), residency=residency,
-        depth=depth)
+        "tgn", idx, coef, ts, x, _row_table(ren, mem0.shape[1]), mask, mem0,
+        freq, w_in, wx, wh, b, tn=tn, td=td, interpret=_interpret(),
+        residency=residency, depth=depth)
     return outs[:, :, :n, :h], memT[..., :h]
 
 
@@ -503,7 +509,6 @@ def _shard_batch(family: str, run, args, device):
     the leading B grid axis splits across devices (streams are
     independent — no collectives), shared params replicate. Covers the
     Pallas engine AND the force-ref oracle path identically."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_stream_mesh
@@ -516,8 +521,9 @@ def _shard_batch(family: str, run, args, device):
     batch_args = _STREAM_DISPATCH[family][2]
     in_specs = tuple(P(device.axis) if i in batch_args else P()
                      for i in range(len(args)))
-    return shard_map(run, mesh=make_stream_mesh(device), in_specs=in_specs,
-                     out_specs=P(device.axis), check_rep=False)
+    return jax.shard_map(run, mesh=make_stream_mesh(device),
+                         in_specs=in_specs, out_specs=P(device.axis),
+                         check_vma=False)
 
 
 def _stream_dispatch(family: str, batched: bool, args, kwargs, *, tn, td,

@@ -12,20 +12,49 @@ single kernel body — ``_stream_engine_kernel`` — owns the stream protocol:
   * stream-boundary **init** (each stream loads its own state at its first
     program) and **drain** (each (l, d) window writes its final state block
     at the stream's last program);
-  * **ping-pong scratch parity** for neighbour-aggregated states (read the
-    t-1 buffer, write the t buffer, swapped by t's parity — the V1
+  * **ping-pong plane parity** for neighbour-aggregated states (read the
+    t-1 plane, write the t plane, swapped by t's parity — the V1
     ping-pong carry pushed down into the kernel);
   * **live-gating**: the between-snapshot weight-evolution hook only runs
     on live snapshots, so serve no-op tail padding never advances the
     recurrence;
   * **residency policy**: which tensors stay VMEM-resident across the T
-    axis (node-state stores, evolving weights) vs stream per step.
+    axis (node-state stores, evolving weights) vs stream per step;
+  * **row traffic**: the renumber-table-guided gather of a step's node rows
+    out of the global store and the scatter of updated rows back.
 
-The three DGNN families are *declarative cell specs* registered in
-``REGISTRY`` — recurrent state tensors plus a per-step cell body (and, for
-the weights-evolved family, a between-snapshot evolution hook). Callers
+The DGNN families are *declarative cell specs* registered in ``REGISTRY``
+— recurrent state tensors plus a per-step cell body (and, for the
+weights-evolved family, a between-snapshot evolution hook). Callers
 (kernels/ops.py, core/*.py, serve/engine.py) dispatch through the registry
 via ``stream_call(family, ...)``; no family-named kernel exists.
+
+Forms the TPU compiler lowers
+-----------------------------
+Everything inside the kernel body is written in forms Mosaic compiles for
+a real chip, not only for the interpreter:
+
+  * **ELL aggregation as a dense tile matmul.** A node tile's ELL lanes
+    (local source ids + coefficients) become a ``(tn, n_src)`` aggregation
+    matrix built with iota compares (``_ell_matrix``), multiplied into the
+    step-local feature table at full f32 precision (``_dot_exact``) — the
+    MXU does the gather; no vector gather is needed.
+  * **Global-store rows by scalar id.** The per-node global row ids live in
+    SMEM (one ``(1, n_pad)`` table per step); the engine copies one
+    full-width row per loop iteration (``_Engine.gather_step`` /
+    ``gather_tile`` / ``scatter_tile``), dropping the ``g_rows`` sentinel
+    of padding rows. Neighbour aggregations over the t-1 store first
+    gather the step's node rows into a local table, then aggregate that
+    table with the tile matrix — the same values the renumbered per-step
+    path gathers.
+  * **Per-row vectors** (node masks) are ``(tn, 1)`` column blocks and
+    scalar flags (EvolveGCN's live flag) a whole-array SMEM operand, so
+    every VMEM block meets the (8, 128) tiling rule.
+
+Row copies and output tiles address full state rows, so on the chip the
+state window must span the whole feature width: D-blocked layouts
+(``td < d_pad``) lower in interpret mode only (the compiler refuses a
+dynamic row access at a column offset).
 
 D-axis blocking (VMEM-oversized state stores)
 ---------------------------------------------
@@ -36,15 +65,16 @@ bodies address state exclusively through ``(n_global, td)`` column windows
 weights are re-packed host-side into per-block gate tiles
 ``(D, rows, n_gates*td)`` so each program's weight/gate working set is
 ``td``-sized. The blocking is exact, NOT a block-diagonal approximation:
-the hidden-to-gate matmul still consumes the full-width t-1 state (with
-D > 1 the per-tile aggregation is computed once per (t, j) at ``d == 0``
-into a cache scratch and re-read by the other d blocks; single-block
-layouts compute it inline with no cache scratch), only the gate columns
-and state writes are blocked. EvolveGCN's matrix-GRU evolves each weight
-COLUMN independently (columns are the GRU batch), so its per-(l, d-block)
-evolution is exact as well, and the documented padded-rows-stay-zero
-invariant holds per block. ``td=None`` (one block) reproduces the fully
-resident layout bit-for-bit.
+the hidden-to-gate matmul still consumes the full-width t-1 state (the
+step's local row table is gathered once per step; with D > 1 the per-tile
+aggregation is computed once per (t, j) at ``d == 0`` into a cache scratch
+and re-read by the other d blocks; single-block layouts compute it inline
+with no cache scratch), only the gate columns and state writes are
+blocked. EvolveGCN's matrix-GRU evolves each weight COLUMN independently
+(columns are the GRU batch), so its per-(l, d-block) evolution is exact
+as well, and the documented padded-rows-stay-zero invariant holds per
+block. ``td=None`` (one block) reproduces the fully resident layout
+bit-for-bit.
 
 HBM-paged residency (``residency="hbm_paged"``)
 -----------------------------------------------
@@ -53,7 +83,7 @@ still occupies VMEM scratch, capping ``n_global × hidden`` at VMEM size.
 The ``hbm_paged`` residency policy makes the same move the FPGA lineage
 makes with DDR/HBM-resident state and multi-buffered streaming: paged
 stores stay in HBM for the whole stream — the state enters the kernel as
-an operand with ``memory_space=pltpu.ANY``, aliased in-place onto an
+an operand with ``memory_space=pl.ANY``, aliased in-place onto an
 output via ``input_output_aliases`` — and the engine stages exactly the
 ``(n_global, td)`` column window each program needs through explicit
 ``pltpu.make_async_copy`` DMA:
@@ -63,15 +93,15 @@ output via ``input_output_aliases`` — and the engine stages exactly the
     states the read PLANE of an HBM A/B plane pair is selected by t's
     parity, and the stage-in doubles as the copy-forward (untouched rows
     ride staging into the write plane);
-  * **cell windows**: ``state_window``/``state_scatter``/``state_block``
+  * **cell windows**: ``gather_tile``/``scatter_tile``/``state_block``
     resolve to the staging buffer — cell bodies are residency-agnostic;
   * **ring-buffered full-width reads**: states declared ``full_read``
     (the t-1 store feeding aggregations/gates) sweep ALL D windows
-    through a ``depth``-deep ring of staging buffers —
-    ``_Engine.paged_fill`` starts window w+depth's copy before computing
-    window w (depth 2 = double-buffered, 4 = quad) — and the per-window
-    fill writes the same cache columns the resident path fills, so the
-    float math is bit-identical;
+    through a ``depth``-deep ring of staging buffers at each step's first
+    program — ``_Engine.paged_fill`` starts window w+depth's copy before
+    consuming window w (depth 2 = double-buffered, 4 = quad) — and the
+    per-window row gather fills the same local-table columns the resident
+    path fills, so the float math is bit-identical;
   * **write-back** (at the window's last tile, after the cell and the
     live-gated evolve hook): the dirty staging window is DMA'd to the
     write view (ping-pong: the opposite plane; row/weights: in place).
@@ -110,23 +140,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# per-tile ELL aggregation over a step-resident feature table (local ids):
-# shared with the per-step V2 kernels, same math by construction.
 from repro.graph.padding import round_up as _round_up
-from repro.kernels.dgnn_fused import _agg as _agg_local
-from repro.kernels.dgnn_fused import _agg_edge as _agg_local_edge
 
 
-def _agg_store(gidx, coef, store):
-    """ELL aggregation straight out of the global VMEM store (global ids).
+def _ell_matrix(idx, coef, n_src: int):
+    """Dense ``(tn, n_src)`` aggregation matrix of one ELL node tile:
+    ``A[r, c] = sum_k coef[r, k] * (idx[r, k] == c)``. ``A @ table`` is the
+    tile's ELL aggregation over a step-local ``(n_src, width)`` table;
+    coef-0 padding lanes contribute nothing whatever id they carry. Built
+    from iota compares over static lane slices — the in-kernel gather form
+    the TPU compiler lowers."""
+    tn, k = idx.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tn, n_src), 1)
+    a = jnp.zeros((tn, n_src), coef.dtype)
+    for kk in range(k):
+        a = a + jnp.where(idx[:, kk:kk + 1] == cols, coef[:, kk:kk + 1], 0.0)
+    return a
 
-    Lanes with coef != 0 always reference real (renumbered) nodes, so the
-    store row equals the masked local h the per-step path would gather;
-    coef-0 padding lanes are killed regardless of the row they point at.
-    """
-    tn, k = gidx.shape
-    g = jnp.take(store, gidx.reshape(-1), axis=0).reshape(tn, k, store.shape[1])
-    return (g * coef[..., None]).sum(axis=1)
+
+def _lane(x, kk):
+    """Lane ``kk`` (traced) of a ``(rows, k)`` value as a ``(rows, 1)``
+    column: a masked lane sum, exact since every other lane adds zero."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lanes == kk, x, 0), axis=1, keepdims=True)
+
+
+def _dot_exact(a, b):
+    """``a @ b`` at full f32 precision — every matmul of the engine. The
+    model is f32 end to end, and the aggregation matmul stands in for a
+    gather, so no operand may round through bf16 (the compiler's default
+    for f32 operands on the chip need not be full precision)."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _pad_dim(a, n2: int, axis: int, fill=0):
@@ -159,8 +204,9 @@ def _pack_gate_blocks(w, n_gates: int, td: int):
 
 
 def _pack_gate_bias(b, n_gates: int, td: int):
-    """(n_gates*h,) -> (D, n_gates*td) per-block gate bias."""
-    return _pack_gate_blocks(b[None], n_gates, td)[:, 0]
+    """(n_gates*h,) -> (D, 1, n_gates*td) per-block gate bias (a row
+    vector per block, so its VMEM block meets the tiling rule)."""
+    return _pack_gate_blocks(b[None], n_gates, td)
 
 
 # ------------------------------------------------------------------------
@@ -173,8 +219,8 @@ class StateDef:
     kind:
       "pingpong"  neighbour-aggregated node state: within a step every
                   tile must see the t-1 store while tiles write the t
-                  store, so the engine keeps an A/B pair swapped by t's
-                  parity (scratch ``(n_global, d_pad)`` each).
+                  store, so the engine keeps an A/B plane pair swapped by
+                  t's parity (scratch ``(2, n_global, d_pad)``).
       "row"       own-row node state (each row read/written by exactly
                   one tile per step): a single ``(n_global, d_pad)``
                   buffer suffices.
@@ -185,7 +231,7 @@ class StateDef:
     state (aggregations / hidden-to-gate matmuls), not just the current
     (d) window. Under ``hbm_paged`` residency such states sweep all D
     windows through the depth-buffered DMA ring (``_Engine.paged_fill``)
-    into the family's cache scratch.
+    into the family's local row table.
     """
 
     name: str
@@ -221,25 +267,31 @@ RESIDENCY_MODES = ("vmem", "hbm_paged")
 #: (window d+1 copies in while window d computes), 4 = quad-buffered.
 BUFFER_DEPTHS = (1, 2, 4)
 
-#: VMEM scratch budget enforced at launch assembly: a resident layout
-#: whose scratch exceeds this must page (``residency="hbm_paged"``).
-#: Module-level so tests can tighten it to exercise the oversized-store
-#: path at CI-friendly sizes; 16 MiB is the per-core hardware figure.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+#: Scoped-VMEM limit every launch asks the compiler for
+#: (``vmem_limit_bytes``), and the scratch budget enforced at launch
+#: assembly: a resident layout whose scratch exceeds it must page
+#: (``residency="hbm_paged"``). The compiler's default scoped limit on
+#: TPU v5e is 16 MiB, which a resident GCRN-M2 launch at BC-Alpha size
+#: (3,468-row stores, double-buffered state blocks) exceeds from B=5
+#: streams up; 64 MiB is half of a v5e's 128 MiB of VMEM. Module-level
+#: so tests can tighten it to exercise the oversized-store path at
+#: CI-friendly sizes.
+VMEM_BUDGET_BYTES = 64 * 1024 * 1024
 
 
 # ------------------------------------------------------------------------
-# Ping-pong plane parity, as pure functions. The HBM plane pair of a paged
-# pingpong state and the host-side final-plane select must agree on one
-# parity scheme; keeping all three derivations here (and nowhere else)
-# makes the parity a checkable contract — repro.analysis simulates a
-# T-step stream through these helpers and cross-checks read-after-write
-# consistency, and the engine's read/write views call them directly.
+# Ping-pong plane parity, as pure functions. The VMEM plane pair of a
+# resident pingpong state, the HBM plane pair of a paged one and the
+# host-side final-plane select must agree on one parity scheme; keeping
+# all derivations here (and nowhere else) makes the parity a checkable
+# contract — repro.analysis simulates a T-step stream through these
+# helpers and cross-checks read-after-write consistency, and the engine's
+# read/write views call them directly.
 
 def paged_read_plane(t):
-    """Plane of a paged pingpong pair holding the t-1 state at step t
-    (the step's READ view). Plane 0 holds the initial state (builds stack
-    ``[state0, zeros]``), so step 0 reads plane 0."""
+    """Plane of a pingpong pair holding the t-1 state at step t (the
+    step's READ view). Plane 0 holds the initial state (init / builds
+    stack ``[state0, zeros]``), so step 0 reads plane 0."""
     return t % 2
 
 
@@ -341,8 +393,8 @@ class _StateMeta:
     kind: str
     in_idx: int     # position of the state's initial value in the inputs
     out_idx: int    # position of the drained final state in the outputs
-    scr_idx: int    # resident: first scratch slot (pingpong uses scr_idx,
-                    # scr_idx+1); paged: the (G, td) staging slot
+    scr_idx: int    # resident: the store scratch (pingpong: its (2, G,
+                    # d_pad) plane pair); paged: the (G, td) staging slot
     ring_idx: int = -1   # paged full_read states: (depth, G, td) DMA ring
     sem_idx: int = -1    # paged states: DMA semaphore array (depth+1,) —
                          # slots [0, depth) ring, slot depth stage-in/
@@ -354,12 +406,18 @@ class _Meta:
     n_in: int
     n_out: int
     states: tuple[_StateMeta, ...]
-    live_idx: Optional[int]       # input index of the (B, T) live flag
+    live_idx: Optional[int]       # input index of the (B, T) SMEM live flag
     td: int
+    tn: int
+    n_dblocks: int                # D = d_pad // td
     temporal: str = "dense"       # must equal the CellSpec's declaration
     paged: bool = False           # hbm_paged residency selected
     depth: int = 1                # DMA staging-ring depth (paged only)
     g_rows: int = 0               # state-store rows G (node families)
+    rows_idx: Optional[int] = None  # input index of the (B, T, 1, n_pad)
+                                    # SMEM global-row-id table
+    stage_idx: Optional[int] = None  # scratch index of the (tn, td)
+                                     # row-staging tile
 
 
 @dataclass
@@ -380,11 +438,12 @@ class _Launch:
 class _Engine:
     """Per-program view of the engine grid handed to cell/evolve hooks."""
 
-    def __init__(self, meta: _Meta, outs=None, scr=None):
+    def __init__(self, meta: _Meta, ins=None, outs=None, scr=None):
         self.meta = meta
         self.td = meta.td
         self.paged = meta.paged
         self.g_rows = meta.g_rows
+        self._ins = ins
         self._outs = outs
         self._scr = scr
         self.b = pl.program_id(0)
@@ -393,78 +452,118 @@ class _Engine:
         self.d = pl.program_id(3)
         self.j = pl.program_id(4)
         self.n_layers = pl.num_programs(2)
-        self.n_dblocks = pl.num_programs(3)
+        self.n_dblocks = meta.n_dblocks
         self.n_tiles = pl.num_programs(4)
-        # state after step t-1 lives in the A buffer on even t
-        self.even = (self.t % 2) == 0
-        self.blk = pl.ds(self.d * meta.td, meta.td)
+        self.blk = self.window(self.d)
+        # this program's node-tile rows of step-local (n_pad, ...) tables
+        self.rows = pl.ds(pl.multiple_of(self.j * meta.tn, meta.tn), meta.tn)
         # each stream loads its state at its own first program (full width:
         # later d blocks read the full t-1 store through the caches)
         self.stream_start = jnp.logical_and(
             self.t == 0, jnp.logical_and(self.d == 0, self.j == 0))
         self.first_dblock = self.d == 0
+        # first program of each (t, l): the step-local row tables fill here
+        self.step_start = jnp.logical_and(self.d == 0, self.j == 0)
         self.last_tile = self.j == self.n_tiles - 1
         # last (t, j) program of the CURRENT stream — drain point for the
         # (l, d) window's state block
         self.stream_done = jnp.logical_and(
             self.t == pl.num_programs(1) - 1, self.last_tile)
 
-    # ---------------------------------------------------- state views ----
+    def window(self, w):
+        """Column window ``w`` of a d_pad-wide array: the whole width when
+        there is one block (static, so row copies lower on the chip)."""
+        if self.meta.n_dblocks == 1:
+            return slice(None)
+        start = w * self.td
+        if not isinstance(w, int):
+            start = pl.multiple_of(start, self.td)
+        return pl.ds(start, self.td)
 
-    def dslice(self, val, axis: int = -1):
-        """This program's td-column window of a full-width VALUE."""
-        return jax.lax.dynamic_slice_in_dim(val, self.d * self.td, self.td,
-                                            axis=axis)
+    # ---------------------------------------------------- row traffic ----
 
-    def state_read(self, scr, i: int):
-        """Full-width t-1 view of state ``i`` (cache-fill at d == 0)."""
-        if self.paged:
-            raise RuntimeError(
-                "full-width state_read is unavailable under hbm_paged "
-                "residency — sweep the windows with paged_fill instead")
+    def row_id(self, r):
+        """Global store row of this step's local node ``r`` (``g_rows`` is
+        the drop sentinel of padding rows), read from the SMEM table."""
+        return self._ins[self.meta.rows_idx][0, 0, 0, r]
+
+    def _copy_rows(self, n: int, rid, src, dst):
+        """``dst[r] = src[rid(r)]`` for ``r < n``, one full-width row per
+        iteration; sentinel rows read as zero."""
+        g_rows = self.g_rows
+
+        def body(r, carry):
+            g = rid(r)
+            row = src[pl.ds(jnp.minimum(g, g_rows - 1), 1), :]
+            dst[pl.ds(r, 1), :] = jnp.where(g < g_rows, row, 0.0)
+            return carry
+
+        jax.lax.fori_loop(0, n, body, 0)
+
+    def _store(self, i: int, plane):
+        """Ref view of resident state i's ``(G, d_pad)`` store (pingpong:
+        the given plane of its pair)."""
         sm = self.meta.states[i]
-        if sm.kind == "pingpong":
-            return jnp.where(self.even, scr[sm.scr_idx][...],
-                             scr[sm.scr_idx + 1][...])
-        return scr[sm.scr_idx][...]
+        ref = self._scr[sm.scr_idx]
+        return ref.at[plane] if sm.kind == "pingpong" else ref
 
-    def state_window(self, scr, i: int):
-        """This (d) column window of state ``i`` (t-1 view for pingpong).
-        Paged: the staged window (stage-in'd from the HBM read view at the
-        window's first tile, so it holds the t-1 values)."""
-        sm = self.meta.states[i]
+    def _window_view(self, i: int, write: bool):
+        """State i's current (d) column window: the t-1 view, or the step's
+        write view. Paged: the staging buffer (stage-in'd from the HBM read
+        view at the window's first tile, written back at its last)."""
         if self.paged:
-            return scr[sm.scr_idx][...]
-        if sm.kind == "pingpong":
-            return jnp.where(self.even, scr[sm.scr_idx][:, self.blk],
-                             scr[sm.scr_idx + 1][:, self.blk])
-        return scr[sm.scr_idx][:, self.blk]
+            return self._scr[self.meta.states[i].scr_idx]
+        plane = (paged_write_plane(self.t) if write
+                 else paged_read_plane(self.t))
+        return self._store(i, plane).at[:, self.blk]
 
-    def state_scatter(self, scr, i: int, rowg, val):
-        """Scatter this (d, tile) block of the new state; rowg == n_global
-        marks padding rows (the sink convention) and mode="drop" discards
-        them. Pingpong states write the step's parity-selected buffer;
-        paged states scatter into the staging window (written back to the
-        HBM write view at the window's last tile)."""
-        sm = self.meta.states[i]
-        blk = self.blk
+    def gather_step(self, i: int, dst):
+        """``dst`` (n_pad, d_pad) <- the full-width t-1 row of state i of
+        every local node of this step (zero on padding rows). Call at
+        ``step_start``: later programs of the step read the table while
+        tiles scatter the step's updates. Paged: the t-1 windows sweep
+        through the DMA ring and fill the table window by window."""
+        n = dst.shape[0]
         if self.paged:
-            stg = scr[sm.scr_idx]
-            stg[...] = stg[...].at[rowg].set(val, mode="drop")
-            return
-        if sm.kind == "pingpong":
-            a_ref, b_ref = scr[sm.scr_idx], scr[sm.scr_idx + 1]
-
-            @pl.when(self.even)
-            def _wr_b():
-                b_ref[:, blk] = b_ref[:, blk].at[rowg].set(val, mode="drop")
-
-            @pl.when(jnp.logical_not(self.even))
-            def _wr_a():
-                a_ref[:, blk] = a_ref[:, blk].at[rowg].set(val, mode="drop")
+            self.paged_fill(i, lambda w, wblk, slot: self._copy_rows(
+                n, self.row_id, slot, dst.at[:, wblk]))
         else:
-            s_ref = scr[sm.scr_idx]
-            s_ref[:, blk] = s_ref[:, blk].at[rowg].set(val, mode="drop")
+            self._copy_rows(n, self.row_id,
+                            self._store(i, paged_read_plane(self.t)), dst)
+
+    def gather_tile(self, i: int):
+        """This tile's own (tn, td) rows of state i's current window (t-1
+        values: a tile reads its rows before scattering), copied through
+        the row-staging tile."""
+        stage = self._scr[self.meta.stage_idx]
+        base = self.j * self.meta.tn
+        self._copy_rows(stage.shape[0], lambda r: self.row_id(base + r),
+                        self._window_view(i, write=False), stage)
+        return stage[...]
+
+    def scatter_tile(self, i: int, val):
+        """State i's write window <- ``val`` (tn, td), one row per local
+        node of this tile, copied through the row-staging tile; padding
+        rows (the ``g_rows`` sentinel) drop. Pingpong states write the
+        step's parity-selected plane; paged states scatter into the
+        staging window (written back to the HBM write view at the
+        window's last tile)."""
+        src = self._scr[self.meta.stage_idx]
+        src[...] = val
+        dst = self._window_view(i, write=True)
+        g_rows = self.g_rows
+        base = self.j * self.meta.tn
+
+        def body(r, carry):
+            g = self.row_id(base + r)
+
+            @pl.when(g < g_rows)
+            def _store_row():
+                dst[pl.ds(g, 1), :] = src[pl.ds(r, 1), :]
+
+            return carry
+
+        jax.lax.fori_loop(0, src.shape[0], body, 0)
 
     def state_block(self, scr, i: int):
         """Layer l's (d_pad, td) column block of a weights-kind state."""
@@ -539,12 +638,12 @@ class _Engine:
 
     def paged_fill(self, i: int, fill):
         """Ring-buffered sweep over ALL D column windows of paged state
-        i's t-1 (read) view: ``fill(w, wblk, window)`` runs per window w
-        with ``window`` the (G, td) staged value, while window w+depth's
-        DMA is already in flight (depth 2 = double-, 4 = quad-buffered;
-        depth 1 degenerates to synchronous per-window copies). The
-        per-window fill writes disjoint cache columns, so the float math
-        matches the resident full-width fill bit-for-bit."""
+        i's t-1 (read) view: ``fill(w, wblk, slot)`` runs per window w
+        with ``slot`` the (G, td) ring buffer holding it, while window
+        w+depth's DMA is already in flight (depth 2 = double-, 4 =
+        quad-buffered; depth 1 degenerates to synchronous per-window
+        copies). The per-window fills write disjoint table columns, so
+        the float math matches the resident full-width fill bit-for-bit."""
         sm = self.meta.states[i]
         ring = self._scr[sm.ring_idx]
         sems = self._scr[sm.sem_idx]
@@ -555,7 +654,7 @@ class _Engine:
         def _start(w):
             slot = w % depth
             dma = _async_copy(
-                self._read_view(i, pl.ds(w * self.td, self.td)),
+                self._read_view(i, self.window(w)),
                 ring.at[slot], sems.at[slot],
                 op="ring", state=i, window=w, slot=slot)
             dma.start()
@@ -565,7 +664,7 @@ class _Engine:
             _start(w)
         for w in range(n_win):
             dmas.pop(w).wait()
-            fill(w, pl.ds(w * self.td, self.td), ring[w % depth])
+            fill(w, self.window(w), ring.at[w % depth])
             if w + depth < n_win:
                 _start(w + depth)
 
@@ -578,7 +677,7 @@ def _stream_engine_kernel(cell, evolve, meta: _Meta, *refs):
     ins = refs[:meta.n_in]
     outs = refs[meta.n_in:meta.n_in + meta.n_out]
     scr = refs[meta.n_in + meta.n_out:]
-    eng = _Engine(meta, outs, scr)
+    eng = _Engine(meta, ins, outs, scr)
 
     if meta.paged:
         # --- paged stage-in (engine-owned): the state lives in HBM (the
@@ -596,15 +695,15 @@ def _stream_engine_kernel(cell, evolve, meta: _Meta, *refs):
         # --- stream-boundary init (engine-owned): every stream
         # re-initializes the scratch from its OWN state block at its first
         # program, so streams reuse the buffers serially and each restarts
-        # the ping-pong at even parity. Weight states init per layer (each
-        # l has its own first program on the (d==0, j==0) plane).
+        # the ping-pong at plane 0. Weight states init per layer (each l
+        # has its own first program on the (d==0, j==0) plane).
         for sm in meta.states:
             in_ref = ins[sm.in_idx]
 
             @pl.when(eng.stream_start)
             def _init(sm=sm, in_ref=in_ref):
                 if sm.kind == "pingpong":
-                    scr[sm.scr_idx][...] = in_ref[0]
+                    scr[sm.scr_idx][0] = in_ref[0]
                 elif sm.kind == "row":
                     scr[sm.scr_idx][...] = in_ref[0]
                 else:  # weights: full (d_pad, d_pad) block of layer l
@@ -617,15 +716,12 @@ def _stream_engine_kernel(cell, evolve, meta: _Meta, *refs):
         for sm in meta.states:
             if sm.kind != "pingpong":
                 continue
-            a_ref, b_ref = scr[sm.scr_idx], scr[sm.scr_idx + 1]
+            st = scr[sm.scr_idx]
 
-            @pl.when(jnp.logical_and(eng.j == 0, eng.even))
-            def _fwd_ab(a_ref=a_ref, b_ref=b_ref):
-                b_ref[:, eng.blk] = a_ref[:, eng.blk]
-
-            @pl.when(jnp.logical_and(eng.j == 0, jnp.logical_not(eng.even)))
-            def _fwd_ba(a_ref=a_ref, b_ref=b_ref):
-                a_ref[:, eng.blk] = b_ref[:, eng.blk]
+            @pl.when(eng.j == 0)
+            def _fwd(st=st):
+                st[paged_write_plane(eng.t), :, eng.blk] = (
+                    st[paged_read_plane(eng.t), :, eng.blk])
 
     # --- the family's per-(t, l, d, j) cell body
     cell(eng, ins, outs, scr)
@@ -634,7 +730,7 @@ def _stream_engine_kernel(cell, evolve, meta: _Meta, *refs):
     # the live flag: no-op (all-padding) snapshots are not steps of the
     # stream and must never advance the recurrence.
     if evolve is not None:
-        live = ins[meta.live_idx][0, 0] > 0
+        live = ins[meta.live_idx][eng.b, eng.t] > 0
 
         @pl.when(jnp.logical_and(eng.last_tile, live))
         def _evolve():
@@ -661,9 +757,8 @@ def _stream_engine_kernel(cell, evolve, meta: _Meta, *refs):
             @pl.when(eng.stream_done)
             def _drain(sm=sm, out_ref=out_ref):
                 if sm.kind == "pingpong":
-                    a_ref, b_ref = scr[sm.scr_idx], scr[sm.scr_idx + 1]
-                    out_ref[0] = jnp.where(eng.even, b_ref[:, eng.blk],
-                                           a_ref[:, eng.blk])
+                    out_ref[0] = scr[sm.scr_idx][paged_write_plane(eng.t),
+                                                 :, eng.blk]
                 elif sm.kind == "row":
                     out_ref[0] = scr[sm.scr_idx][:, eng.blk]
                 else:
@@ -687,35 +782,32 @@ def launch_scratch_bytes(launch: _Launch) -> int:
 def stream_vmem_bytes(family: str, *, g_rows: int = 0, n_pad: int = 0,
                       d_pad: int = 0, din: int = 0, dmid: int = 0,
                       n_layers: int = 1, td: Optional[int] = None,
-                      residency: str = "vmem", depth: int = 2,
-                      itemsize: int = 4) -> int:
+                      tn: int = 128, residency: str = "vmem",
+                      depth: int = 2, itemsize: int = 4) -> int:
     """Plan-time VMEM scratch estimate per family/residency/blocking —
     the per-family scratch tables (docs/stream_engine.md) as a formula.
     Bit-equal to ``launch_scratch_bytes`` of the assembled launch
     (tests/test_paged.py pins this for every family and variant).
 
-    ``g_rows`` counts the state-store rows (n_global + sentinel) of node
-    families; ``n_pad`` the padded per-step node count; ``din``/``dmid``
-    the gcrn aggregation-input / stacked GCN-mid widths."""
+    ``g_rows`` counts the state-store rows of node families; ``n_pad``
+    the padded per-step node count; ``din``/``dmid`` the gcrn
+    aggregation-input / stacked GCN-mid widths; ``tn`` the node tile.
+    Node families always hold one step-local ``(n_pad, d_pad)`` row table
+    of the t-1 store and one ``(tn, td)`` row-staging tile."""
     paged = residency == "hbm_paged"
     if paged and family == "static_gcn":
         raise ValueError("static_gcn has no state to page")
     t = td if td is not None else d_pad
     n_win = -(-d_pad // t) if t else 1  # ceil
-    cached = n_win > 1
+    cached = n_win > 1 or paged
+    table = n_pad * d_pad + tn * t        # row table + row-staging tile
     cells = 0
     if family == "gcrn":
-        if paged:
-            cells = (2 + depth) * g_rows * t + n_pad * (din + d_pad)
-        else:
-            cells = 3 * g_rows * d_pad + (
-                n_pad * (din + d_pad) if cached else 0)
+        store = (2 + depth) * g_rows * t if paged else 3 * g_rows * d_pad
+        cells = store + table + (n_pad * (din + d_pad) if cached else 0)
     elif family == "stacked":
-        if paged:
-            cells = (1 + depth) * g_rows * t + n_pad * (dmid + d_pad)
-        else:
-            cells = g_rows * d_pad + (
-                n_pad * (dmid + d_pad) if cached else 0)
+        store = (1 + depth) * g_rows * t if paged else g_rows * d_pad
+        cells = store + table + (n_pad * dmid if cached else 0)
     elif family == "evolve":
         if paged:
             cells = d_pad * t + 3 * n_pad * d_pad
@@ -723,11 +815,8 @@ def stream_vmem_bytes(family: str, *, g_rows: int = 0, n_pad: int = 0,
             cells = (n_layers * d_pad * d_pad + 2 * n_pad * d_pad
                      + (n_pad * d_pad if cached else 0))
     elif family == "tgn":
-        if paged:
-            cells = (1 + depth) * g_rows * t + 2 * n_pad * d_pad
-        else:
-            cells = 2 * g_rows * d_pad + (
-                2 * n_pad * d_pad if cached else 0)
+        store = (1 + depth) * g_rows * t if paged else 2 * g_rows * d_pad
+        cells = store + table + (n_pad * d_pad if cached else 0)
     elif family == "static_gcn":
         cells = 2 * n_pad * d_pad + (n_pad * d_pad if cached else 0)
     else:
@@ -806,9 +895,11 @@ def stream_call(family: str, *args, tn: int = 128, td: Optional[int] = None,
         out_shape=launch.out_shape,
         scratch_shapes=launch.scratch,
         input_output_aliases=launch.aliases,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",) * len(launch.grid)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(launch.grid),
+            vmem_limit_bytes=VMEM_BUDGET_BYTES),
         interpret=interpret,
+        name=f"stream_engine_{family}",
     )(*launch.inputs)
     if paged:
         # node-state planes come back as (B, P, G, d_pad): select the
@@ -825,70 +916,129 @@ def stream_call(family: str, *args, tn: int = 128, td: Optional[int] = None,
 
 
 # ------------------------------------------------------------------------
+# Block specs shared by the families. Every per-step node table is
+# (B, T, n_pad, width); per-node vectors ride as (tn, 1) column blocks and
+# the global-row-id table as a (1, n_pad) SMEM block per step.
+
+def _tile_spec(tn: int, width: int):
+    """One node tile of a per-step (B, T, n_pad, width) array."""
+    return pl.BlockSpec((1, 1, tn, width), lambda bi, t, l, d, j: (bi, t, j, 0))
+
+
+def _step_spec(n: int, width: int):
+    """The whole (n_pad, width) table of a step (e.g. node features)."""
+    return pl.BlockSpec((1, 1, n, width), lambda bi, t, l, d, j: (bi, t, 0, 0))
+
+
+def _row_ids_spec(n: int):
+    """The step's (1, n_pad) global-row-id table, in SMEM."""
+    return pl.BlockSpec((1, 1, 1, n), lambda bi, t, l, d, j: (bi, t, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def _out_tile_spec(tn: int, td: int):
+    """Per-step output tile (node tile j, state column block d)."""
+    return pl.BlockSpec((1, 1, tn, td), lambda bi, t, l, d, j: (bi, t, j, d))
+
+
+def _gate_specs(rows: int, n_gates: int, td: int, wx_rows: int):
+    """Per-d-block gate tiles: wx (D, wx_rows, g*td), wh (D, rows, g*td),
+    bias (D, 1, g*td)."""
+    dblk = lambda bi, t, l, d, j: (d, 0, 0)
+    return [pl.BlockSpec((1, wx_rows, n_gates * td), dblk),
+            pl.BlockSpec((1, rows, n_gates * td), dblk),
+            pl.BlockSpec((1, 1, n_gates * td), dblk)]
+
+
+def _edge_agg_spec(edge_agg, tn: int, width: int, dtype):
+    """(array, spec) of a pre-aggregated (B, T, n_pad, width) edge term;
+    without one, a single pinned zero block the kernel never reads."""
+    if edge_agg is not None:
+        return edge_agg, _tile_spec(tn, width)
+    return (jnp.zeros((1, 1, tn, width), dtype),
+            pl.BlockSpec((1, 1, tn, width),
+                         lambda bi, t, l, d, j: (0, 0, 0, 0)))
+
+
+def _node_state_io(h0, d_pad: int, td: int, pingpong: bool, paged: bool):
+    """(input, in_spec, out_spec, out_shape) of one (B, G, h) node-state
+    store, zero-padded to d_pad columns. Resident: the store in, drained
+    per d window out. Paged: an HBM plane stack (pingpong: ``[state0,
+    zeros]`` A/B planes; row: one plane) aliased in place onto its
+    output."""
+    h0 = _pad_dim(h0, d_pad, -1)
+    B, G = h0.shape[0], h0.shape[1]
+    if paged:
+        planes = 2 if pingpong else 1
+        h_in = (jnp.stack([h0, jnp.zeros_like(h0)], axis=1) if pingpong
+                else h0[:, None])
+        spec = pl.BlockSpec(memory_space=pl.ANY)
+        return (h_in, spec, spec,
+                jax.ShapeDtypeStruct((B, planes, G, d_pad), h0.dtype))
+    return (h0,
+            pl.BlockSpec((1, G, d_pad), lambda bi, t, l, d, j: (bi, 0, 0)),
+            pl.BlockSpec((1, G, td), lambda bi, t, l, d, j: (bi, 0, d)),
+            jax.ShapeDtypeStruct((B, G, d_pad), h0.dtype))
+
+
+# ------------------------------------------------------------------------
 # GCRN (GC-LSTM): integrated family. Neighbour-aggregated h (ping-pong
 # pair) + own-row c. The hidden-to-gate matmul consumes the FULL-width t-1
-# store (aggregated once per (t, j) into the caches at d == 0); gate
+# store: the step's node rows are gathered once per step into a local
+# table and aggregated per tile (cached at d == 0 when D > 1); gate
 # columns and state writes are d-blocked.
 
+_GCRN_HLOC, _GCRN_CAX, _GCRN_CAH = 2, 4, 5   # scratch slots (both residencies)
+
+
 def _gcrn_cell(has_edge, cached, eng, ins, outs, scr):
-    (idx_ref, gidx_ref, coef_ref, eidx_ref, x_ref, rowg_ref, mask_ref,
-     _h0, _c0, wx_ref, wh_ref, b_ref, emsg_ref) = ins
+    (idx_ref, coef_ref, x_ref, _rowg, mask_ref, _h0, _c0, wx_ref, wh_ref,
+     b_ref, eagg_ref) = ins
     out_ref = outs[0]
+    hloc = scr[_GCRN_HLOC]
+    mask = mask_ref[0, 0]                       # (tn, 1)
+    rows = eng.rows
 
-    idx, gidx = idx_ref[0, 0], gidx_ref[0, 0]
-    coef, eidx = coef_ref[0, 0], eidx_ref[0, 0]
-    rowg = rowg_ref[0, 0]
-    mask = mask_ref[0, 0][:, None]
-    tn = idx.shape[0]
-    rows = pl.ds(eng.j * tn, tn)
+    @pl.when(eng.step_start)
+    def _gather():
+        eng.gather_step(0, hloc)                # t-1 h of the step's nodes
 
-    def _agg_x():
-        x = x_ref[0, 0]
-        return (_agg_local_edge(idx, coef, eidx, x, emsg_ref[0, 0])
-                if has_edge else _agg_local(idx, coef, x))
+    def _aggregates():
+        a = _ell_matrix(idx_ref[0, 0], coef_ref[0, 0], x_ref.shape[2])
+        agg_x = _dot_exact(a, x_ref[0, 0])
+        if has_edge:
+            agg_x = agg_x + eagg_ref[0, 0]
+        return agg_x, _dot_exact(a, hloc[...])
 
     if cached:  # D > 1 or paged: aggregate once per (t, j); d > 0 re-reads
-        cax, cah = scr[3], scr[4]
+        cax, cah = scr[_GCRN_CAX], scr[_GCRN_CAH]
 
         @pl.when(eng.first_dblock)
         def _fill_caches():
-            cax[rows] = _agg_x()
-            if eng.paged:
-                # sweep the t-1 h store's windows through the DMA ring;
-                # the aggregation is columnwise, so per-window fills of
-                # disjoint cache columns equal the full-width fill
-                def _one(w, wblk, sval):
-                    cah[rows, wblk] = _agg_store(gidx, coef, sval)
-
-                eng.paged_fill(0, _one)
-            else:
-                cah[rows] = _agg_store(gidx, coef, eng.state_read(scr, 0))
+            cax[rows], cah[rows] = _aggregates()
 
         agg_x, agg_h = cax[rows], cah[rows]
     else:       # single d block: inline, no scratch round-trip
-        agg_x = _agg_x()
-        agg_h = _agg_store(gidx, coef, eng.state_read(scr, 0))
+        agg_x, agg_h = _aggregates()
 
     td = eng.td
-    gates = agg_x @ wx_ref[0] + agg_h @ wh_ref[0] + b_ref[0][None, :]
+    gates = (_dot_exact(agg_x, wx_ref[0]) + _dot_exact(agg_h, wh_ref[0])
+             + b_ref[0])
     i = gates[:, :td]
     f = gates[:, td:2 * td]
     g = gates[:, 2 * td:3 * td]
     o = gates[:, 3 * td:]
 
-    n_global = eng.g_rows
-    row_safe = jnp.where(rowg < n_global, rowg, 0)
-    c_old = jnp.take(eng.state_window(scr, 1), row_safe, axis=0) * mask
+    c_old = eng.gather_tile(1) * mask
     c_new = (jax.nn.sigmoid(f) * c_old + jax.nn.sigmoid(i) * jnp.tanh(g)) * mask
     h_new = (jax.nn.sigmoid(o) * jnp.tanh(c_new)) * mask
-
-    eng.state_scatter(scr, 0, rowg, h_new)
-    eng.state_scatter(scr, 1, rowg, c_new)
+    eng.scatter_tile(0, h_new)
+    eng.scatter_tile(1, c_new)
     out_ref[0, 0] = h_new
 
 
-def _gcrn_build(neigh_idx, neigh_gidx, neigh_coef, neigh_eidx, node_feat,
-                row_gidx, node_mask, h0, c0, wx, wh, b, edge_msg=None, *,
+def _gcrn_build(neigh_idx, neigh_coef, node_feat, row_gidx, node_mask,
+                h0, c0, wx, wh, b, edge_agg=None, *,
                 tn: int, td: Optional[int], residency: str = "vmem",
                 depth: int = 2):
     B, T, n, k = neigh_idx.shape
@@ -902,103 +1052,78 @@ def _gcrn_build(neigh_idx, neigh_gidx, neigh_coef, neigh_eidx, node_feat,
     cached = D > 1 or paged
     grid = (B, T, 1, D, n // tn)
 
-    h0p = _pad_dim(h0, d_pad, -1)
-    c0p = _pad_dim(c0, d_pad, -1)
     wxp = _pack_gate_blocks(wx, 4, td)                    # (D, din, 4td)
     whp = _pack_gate_blocks(_pad_dim(wh, d_pad, 0), 4, td)  # (D, d_pad, 4td)
-    bp = _pack_gate_bias(b, 4, td)                        # (D, 4td)
+    bp = _pack_gate_bias(b, 4, td)                        # (D, 1, 4td)
+    has_edge = edge_agg is not None
+    eagg, eagg_spec = _edge_agg_spec(edge_agg, tn, din, node_feat.dtype)
+    h_in, h_in_spec, h_out_spec, h_out_shape = _node_state_io(
+        h0, d_pad, td, True, paged)
+    c_in, c_in_spec, c_out_spec, c_out_shape = _node_state_io(
+        c0, d_pad, td, False, paged)
 
-    has_edge = edge_msg is not None
-    if not has_edge:
-        edge_msg = jnp.zeros((B, T, 8, din), node_feat.dtype)
-    e = edge_msg.shape[2]
-
-    tile = lambda bi, t, l, d, j: (bi, t, j, 0)
-    step = lambda bi, t, l, d, j: (bi, t, 0, 0)
-    row = lambda bi, t, l, d, j: (bi, t, j)
-    state_in = lambda bi, t, l, d, j: (bi, 0, 0)
-    state_out = lambda bi, t, l, d, j: (bi, 0, d)
-    out_tile = lambda bi, t, l, d, j: (bi, t, j, d)
-    dblk = lambda bi, t, l, d, j: (d, 0, 0)
-    dblk1 = lambda bi, t, l, d, j: (d, 0)
-
+    tables = [
+        pltpu.VMEM((n, d_pad), h0.dtype),             # t-1 h row table
+        pltpu.VMEM((tn, td), h0.dtype),               # row-staging tile
+    ] + ([
+        pltpu.VMEM((n, din), node_feat.dtype),        # agg_x cache
+        pltpu.VMEM((n, d_pad), h0.dtype),             # agg_h cache
+    ] if cached else [])
     if paged:
         # HBM-resident stores: h as an A/B plane pair (stage-in reads the
         # t%2 plane, write-back the other), c as a single plane; both
-        # aliased in-place onto their outputs. scr layout keeps the cache
-        # slots at the resident positions (3, 4).
-        h_in = jnp.stack([h0p, jnp.zeros_like(h0p)], axis=1)
-        c_in = c0p[:, None]
-        state_in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        state_out_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        state_out_shape = [
-            jax.ShapeDtypeStruct((B, 2, G, d_pad), h0.dtype),
-            jax.ShapeDtypeStruct((B, 1, G, d_pad), c0.dtype),
-        ]
-        states = (_StateMeta("pingpong", in_idx=7, out_idx=1, scr_idx=0,
-                             ring_idx=2, sem_idx=5),
-                  _StateMeta("row", in_idx=8, out_idx=2, scr_idx=1,
-                             sem_idx=6))
-        state_scratch = [
+        # aliased in-place onto their outputs.
+        states = (_StateMeta("pingpong", in_idx=5, out_idx=1, scr_idx=0,
+                             ring_idx=6, sem_idx=7),
+                  _StateMeta("row", in_idx=6, out_idx=2, scr_idx=1,
+                             sem_idx=8))
+        scratch = [
             pltpu.VMEM((G, td), h0.dtype),            # h staging window
             pltpu.VMEM((G, td), c0.dtype),            # c staging window
+        ] + tables + [
             pltpu.VMEM((depth, G, td), h0.dtype),     # h read ring
+            pltpu.SemaphoreType.DMA((depth + 1,)),
+            pltpu.SemaphoreType.DMA((depth + 1,)),
         ]
-        sem_scratch = [pltpu.SemaphoreType.DMA((depth + 1,)),
-                       pltpu.SemaphoreType.DMA((depth + 1,))]
-        aliases = {7: 1, 8: 2}
+        aliases = {5: 1, 6: 2}
     else:
-        h_in, c_in = h0p, c0p
-        state_in_specs = [pl.BlockSpec((1, G, d_pad), state_in)] * 2
-        state_out_specs = [pl.BlockSpec((1, G, td), state_out)] * 2
-        state_out_shape = [
-            jax.ShapeDtypeStruct((B, G, d_pad), h0.dtype),
-            jax.ShapeDtypeStruct((B, G, d_pad), c0.dtype),
-        ]
-        states = (_StateMeta("pingpong", in_idx=7, out_idx=1, scr_idx=0),
-                  _StateMeta("row", in_idx=8, out_idx=2, scr_idx=2))
-        state_scratch = [
-            pltpu.VMEM((G, d_pad), h0.dtype),         # h ping
-            pltpu.VMEM((G, d_pad), h0.dtype),         # h pong
+        states = (_StateMeta("pingpong", in_idx=5, out_idx=1, scr_idx=0),
+                  _StateMeta("row", in_idx=6, out_idx=2, scr_idx=1))
+        scratch = [
+            pltpu.VMEM((2, G, d_pad), h0.dtype),      # h plane pair
             pltpu.VMEM((G, d_pad), c0.dtype),         # c (own-row)
-        ]
-        sem_scratch = []
+        ] + tables
         aliases = {}
 
     meta = _Meta(
-        n_in=13, n_out=3, states=states,
-        live_idx=None, td=td, paged=paged, depth=depth, g_rows=G)
+        n_in=11, n_out=3, states=states, live_idx=None, td=td, tn=tn,
+        n_dblocks=D, paged=paged, depth=depth, g_rows=G, rows_idx=3,
+        stage_idx=3)
     return _Launch(
         grid=grid,
-        inputs=(neigh_idx, neigh_gidx, neigh_coef, neigh_eidx, node_feat,
-                row_gidx, node_mask, h_in, c_in, wxp, whp, bp, edge_msg),
+        inputs=(neigh_idx, neigh_coef, node_feat, row_gidx[:, :, None, :],
+                node_mask[..., None], h_in, c_in, wxp, whp, bp, eagg),
         in_specs=[
-            pl.BlockSpec((1, 1, tn, k), tile),        # neigh_idx (local)
-            pl.BlockSpec((1, 1, tn, k), tile),        # neigh_gidx (global)
-            pl.BlockSpec((1, 1, tn, k), tile),        # neigh_coef
-            pl.BlockSpec((1, 1, tn, k), tile),        # neigh_eidx
-            pl.BlockSpec((1, 1, n, din), step),       # node_feat, per (b, t)
-            pl.BlockSpec((1, 1, tn), row),            # row_gidx
-            pl.BlockSpec((1, 1, tn), row),            # node_mask
-            state_in_specs[0],                        # h0 / h plane pair
-            state_in_specs[1],                        # c0 / c plane
-            pl.BlockSpec((1, din, 4 * td), dblk),     # wx gate tile, per d
-            pl.BlockSpec((1, d_pad, 4 * td), dblk),   # wh gate tile, per d
-            pl.BlockSpec((1, 4 * td), dblk1),         # bias gate tile
-            pl.BlockSpec((1, 1, e, din), step),       # edge messages
+            _tile_spec(tn, k),                        # neigh_idx (local)
+            _tile_spec(tn, k),                        # neigh_coef
+            _step_spec(n, din),                       # node_feat, per (b, t)
+            _row_ids_spec(n),                         # global row ids (SMEM)
+            _tile_spec(tn, 1),                        # node_mask column
+            h_in_spec,                                # h0 / h plane pair
+            c_in_spec,                                # c0 / c plane
+            *_gate_specs(d_pad, 4, td, din),          # wx / wh / bias tiles
+            eagg_spec,                                # edge-message term
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tn, td), out_tile),   # per-step h outputs
-            state_out_specs[0],                       # final h
-            state_out_specs[1],                       # final c
+            _out_tile_spec(tn, td),                   # per-step h outputs
+            h_out_spec,                               # final h
+            c_out_spec,                               # final c
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, n, d_pad), node_feat.dtype),
-        ] + state_out_shape,
-        scratch=state_scratch + ([
-            pltpu.VMEM((n, din), node_feat.dtype),    # agg_x cache
-            pltpu.VMEM((n, d_pad), h0.dtype),         # agg_h cache
-        ] if cached else []) + sem_scratch,
+            h_out_shape, c_out_shape,
+        ],
+        scratch=scratch,
         meta=meta,
         cell=functools.partial(_gcrn_cell, has_edge, cached),
         evolve=None,
@@ -1008,73 +1133,58 @@ def _gcrn_build(neigh_idx, neigh_gidx, neigh_coef, neigh_eidx, node_feat,
 
 # ------------------------------------------------------------------------
 # Stacked DGNN (GCN -> GRU): own-row h only. The GRU's hidden-to-gate
-# matmul reads the FULL-width t-1 row, cached at d == 0 BEFORE this step's
-# first write (rows are tile-owned, so the cache of a tile's rows is never
-# clobbered by other tiles).
+# matmul reads the FULL-width t-1 row: the step's rows are gathered into a
+# local table at the step's first program, BEFORE any tile writes.
+
+_STACKED_HLOC, _STACKED_CNT = 1, 3           # scratch slots (both residencies)
+
 
 def _stacked_cell(has_edge, cached, eng, ins, outs, scr):
-    (idx_ref, coef_ref, eidx_ref, x_ref, rowg_ref, mask_ref, _h0,
-     wg_ref, bg_ref, wx_ref, wh_ref, b_ref, emsg_ref) = ins
+    (idx_ref, coef_ref, x_ref, _rowg, mask_ref, _h0, wg_ref, bg_ref,
+     wx_ref, wh_ref, b_ref, eagg_ref) = ins
     out_ref = outs[0]
-    h_scr = scr[0]
+    hloc = scr[_STACKED_HLOC]
+    mask = mask_ref[0, 0]
+    rows = eng.rows
 
-    idx, coef, eidx = idx_ref[0, 0], coef_ref[0, 0], eidx_ref[0, 0]
-    rowg = rowg_ref[0, 0]
-    mask = mask_ref[0, 0][:, None]
-    tn = idx.shape[0]
-    rows = pl.ds(eng.j * tn, tn)
-    n_global = eng.g_rows
-    row_safe = jnp.where(rowg < n_global, rowg, 0)
+    @pl.when(eng.step_start)
+    def _gather():
+        eng.gather_step(0, hloc)                # t-1 own rows, pre-write
 
     def _node_transform():
-        x = x_ref[0, 0]
-        agg = (_agg_local_edge(idx, coef, eidx, x, emsg_ref[0, 0])
-               if has_edge else _agg_local(idx, coef, x))
-        return agg @ wg_ref[...] + bg_ref[...][None, :]
-
-    def _gather_rows(store):
-        # t-1 own rows, gathered BEFORE this step's first write to them
-        return jnp.take(store, row_safe, axis=0) * mask
+        a = _ell_matrix(idx_ref[0, 0], coef_ref[0, 0], x_ref.shape[2])
+        agg = _dot_exact(a, x_ref[0, 0])
+        if has_edge:
+            agg = agg + eagg_ref[0, 0]
+        return _dot_exact(agg, wg_ref[...]) + bg_ref[...]
 
     if cached:  # D > 1 or paged: once per (t, j); d > 0 re-reads
-        cnt, chold = scr[1], scr[2]
+        cnt = scr[_STACKED_CNT]
 
         @pl.when(eng.first_dblock)
-        def _fill_caches():
+        def _fill_cache():
             cnt[rows] = _node_transform()
-            if eng.paged:
-                # sweep the t-1 h store's windows through the DMA ring;
-                # the gather is columnwise, so per-window fills of
-                # disjoint cache columns equal the full-width fill
-                def _one(w, wblk, sval):
-                    chold[rows, wblk] = _gather_rows(sval)
 
-                eng.paged_fill(0, _one)
-            else:
-                chold[rows] = _gather_rows(h_scr[...])
-
-        nt, h_old_full = cnt[rows], chold[rows]
-    else:       # single d block: read-then-write in one program
+        nt = cnt[rows]
+    else:       # single d block: inline
         nt = _node_transform()
-        h_old_full = _gather_rows(h_scr[...])
 
     td = eng.td
-    gx = nt @ wx_ref[0] + b_ref[0][None, :]
-    gh = h_old_full @ wh_ref[0]
+    gx = _dot_exact(nt, wx_ref[0]) + b_ref[0]
+    gh = _dot_exact(hloc[rows] * mask, wh_ref[0])
     rx, zx, nx = gx[:, :td], gx[:, td:2 * td], gx[:, 2 * td:]
     rh, zh, nh = gh[:, :td], gh[:, td:2 * td], gh[:, 2 * td:]
     r = jax.nn.sigmoid(rx + rh)
     z = jax.nn.sigmoid(zx + zh)
     nn = jnp.tanh(nx + r * nh)
-    h_old = eng.dslice(h_old_full)
+    h_old = hloc[rows, eng.blk] * mask
     h_new = ((1.0 - z) * nn + z * h_old) * mask
-
-    eng.state_scatter(scr, 0, rowg, h_new)
+    eng.scatter_tile(0, h_new)
     out_ref[0, 0] = h_new
 
 
-def _stacked_build(neigh_idx, neigh_coef, neigh_eidx, node_feat, row_gidx,
-                   node_mask, h0, w_gcn, b_gcn, wx, wh, b, edge_msg=None, *,
+def _stacked_build(neigh_idx, neigh_coef, node_feat, row_gidx, node_mask,
+                   h0, w_gcn, b_gcn, wx, wh, b, edge_agg=None, *,
                    tn: int, td: Optional[int], residency: str = "vmem",
                    depth: int = 2):
     B, T, n, k = neigh_idx.shape
@@ -1089,85 +1199,66 @@ def _stacked_build(neigh_idx, neigh_coef, neigh_eidx, node_feat, row_gidx,
     cached = D > 1 or paged
     grid = (B, T, 1, D, n // tn)
 
-    h0p = _pad_dim(h0, d_pad, -1)
     wxp = _pack_gate_blocks(wx, 3, td)                      # (D, dmid, 3td)
     whp = _pack_gate_blocks(_pad_dim(wh, d_pad, 0), 3, td)  # (D, d_pad, 3td)
-    bp = _pack_gate_bias(b, 3, td)                          # (D, 3td)
+    bp = _pack_gate_bias(b, 3, td)                          # (D, 1, 3td)
+    has_edge = edge_agg is not None
+    eagg, eagg_spec = _edge_agg_spec(edge_agg, tn, din, node_feat.dtype)
+    h_in, h_in_spec, h_out_spec, h_out_shape = _node_state_io(
+        h0, d_pad, td, False, paged)
 
-    has_edge = edge_msg is not None
-    if not has_edge:
-        edge_msg = jnp.zeros((B, T, 8, din), node_feat.dtype)
-    e = edge_msg.shape[2]
-
-    tile = lambda bi, t, l, d, j: (bi, t, j, 0)
-    step = lambda bi, t, l, d, j: (bi, t, 0, 0)
-    row = lambda bi, t, l, d, j: (bi, t, j)
-    state_in = lambda bi, t, l, d, j: (bi, 0, 0)
-    state_out = lambda bi, t, l, d, j: (bi, 0, d)
-    out_tile = lambda bi, t, l, d, j: (bi, t, j, d)
-    res2 = lambda bi, t, l, d, j: (0, 0)
-    res1 = lambda bi, t, l, d, j: (0,)
-    dblk = lambda bi, t, l, d, j: (d, 0, 0)
-    dblk1 = lambda bi, t, l, d, j: (d, 0)
-
+    tables = [
+        pltpu.VMEM((n, d_pad), h0.dtype),              # t-1 h row table
+        pltpu.VMEM((tn, td), h0.dtype),                # row-staging tile
+    ] + ([
+        pltpu.VMEM((n, dmid), node_feat.dtype),        # node-transform cache
+    ] if cached else [])
     if paged:
         # HBM-resident own-row store as a single plane, aliased in-place
-        # onto its output; caches stay at the resident positions (1, 2).
-        h_in = h0p[:, None]
-        h_in_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        h_out_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        h_out_shape = jax.ShapeDtypeStruct((B, 1, G, d_pad), h0.dtype)
-        states = (_StateMeta("row", in_idx=6, out_idx=1, scr_idx=0,
-                             ring_idx=3, sem_idx=4),)
-        state_scratch = [pltpu.VMEM((G, td), h0.dtype)]   # h staging window
-        ring_scratch = [pltpu.VMEM((depth, G, td), h0.dtype)]  # h read ring
-        sem_scratch = [pltpu.SemaphoreType.DMA((depth + 1,))]
-        aliases = {6: 1}
+        # onto its output.
+        states = (_StateMeta("row", in_idx=5, out_idx=1, scr_idx=0,
+                             ring_idx=4, sem_idx=5),)
+        scratch = [pltpu.VMEM((G, td), h0.dtype)] + tables + [  # staging
+            pltpu.VMEM((depth, G, td), h0.dtype),               # read ring
+            pltpu.SemaphoreType.DMA((depth + 1,)),
+        ]
+        aliases = {5: 1}
     else:
-        h_in = h0p
-        h_in_spec = pl.BlockSpec((1, G, d_pad), state_in)
-        h_out_spec = pl.BlockSpec((1, G, td), state_out)
-        h_out_shape = jax.ShapeDtypeStruct((B, G, d_pad), h0.dtype)
-        states = (_StateMeta("row", in_idx=6, out_idx=1, scr_idx=0),)
-        state_scratch = [pltpu.VMEM((G, d_pad), h0.dtype)]  # h (own-row)
-        ring_scratch = []
-        sem_scratch = []
+        states = (_StateMeta("row", in_idx=5, out_idx=1, scr_idx=0),)
+        scratch = [pltpu.VMEM((G, d_pad), h0.dtype)] + tables   # h store
         aliases = {}
 
+    res2 = lambda bi, t, l, d, j: (0, 0)
     meta = _Meta(
-        n_in=13, n_out=2, states=states,
-        live_idx=None, td=td, paged=paged, depth=depth, g_rows=G)
+        n_in=12, n_out=2, states=states, live_idx=None, td=td, tn=tn,
+        n_dblocks=D, paged=paged, depth=depth, g_rows=G, rows_idx=3,
+        stage_idx=2)
     return _Launch(
         grid=grid,
-        inputs=(neigh_idx, neigh_coef, neigh_eidx, node_feat, row_gidx,
-                node_mask, h_in, w_gcn, b_gcn, wxp, whp, bp, edge_msg),
+        inputs=(neigh_idx, neigh_coef, node_feat, row_gidx[:, :, None, :],
+                node_mask[..., None], h_in, w_gcn, b_gcn[None], wxp, whp, bp,
+                eagg),
         in_specs=[
-            pl.BlockSpec((1, 1, tn, k), tile),
-            pl.BlockSpec((1, 1, tn, k), tile),
-            pl.BlockSpec((1, 1, tn, k), tile),
-            pl.BlockSpec((1, 1, n, din), step),
-            pl.BlockSpec((1, 1, tn), row),
-            pl.BlockSpec((1, 1, tn), row),
+            _tile_spec(tn, k),                         # neigh_idx (local)
+            _tile_spec(tn, k),                         # neigh_coef
+            _step_spec(n, din),                        # node_feat
+            _row_ids_spec(n),                          # global row ids (SMEM)
+            _tile_spec(tn, 1),                         # node_mask column
             h_in_spec,                                 # h0 / h plane
             pl.BlockSpec((din, dmid), res2),           # GCN weight (full)
-            pl.BlockSpec((dmid,), res1),               # GCN bias
-            pl.BlockSpec((1, dmid, 3 * td), dblk),     # wx gate tile, per d
-            pl.BlockSpec((1, d_pad, 3 * td), dblk),    # wh gate tile, per d
-            pl.BlockSpec((1, 3 * td), dblk1),          # bias gate tile
-            pl.BlockSpec((1, 1, e, din), step),
+            pl.BlockSpec((1, dmid), res2),             # GCN bias
+            *_gate_specs(d_pad, 3, td, dmid),          # wx / wh / bias tiles
+            eagg_spec,                                 # edge-message term
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tn, td), out_tile),
+            _out_tile_spec(tn, td),
             h_out_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, n, d_pad), node_feat.dtype),
             h_out_shape,
         ],
-        scratch=state_scratch + ([
-            pltpu.VMEM((n, dmid), node_feat.dtype),    # node-transform cache
-            pltpu.VMEM((n, d_pad), h0.dtype),          # t-1 h-row cache
-        ] if cached else []) + ring_scratch + sem_scratch,
+        scratch=scratch,
         meta=meta,
         cell=functools.partial(_stacked_cell, has_edge, cached),
         evolve=None,
@@ -1176,73 +1267,76 @@ def _stacked_build(neigh_idx, neigh_coef, neigh_eidx, node_feat, row_gidx,
 
 
 # ------------------------------------------------------------------------
-# EvolveGCN: weights-resident family. No node-resident recurrent state —
-# the recurrence is over the per-layer GCN weights W_l^t, evolved by a
-# matrix-GRU between snapshots (live-gated by the engine). The L grid axis
-# sequences the multi-layer GCN's cross-tile dependency over a ping-pong
-# activation scratch; the d axis blocks W's COLUMNS, which the matrix-GRU
-# evolves independently (columns are the GRU batch), so per-(l, d-block)
-# evolution is exact. Padding convention: all widths zero-padded into a
-# common square d_pad; GRU params padded PER GATE BLOCK
-# (ops._pad_matrix_gru_params); zero-padded weight ROWS stay zero under
-# evolution per block (their gate inputs are identically 0), keeping junk
-# activation columns out of valid output columns.
+# GCN layer over an activation plane pair: the per-(t, l, d, j) body of
+# both layer-sequenced families (EvolveGCN, static GCN). The L grid axis
+# sequences the multi-layer GCN's cross-tile dependency: even layers read
+# plane 0 and write plane 1, odd layers the reverse; layer 0 reads this
+# step's node features.
 
-def _evolve_cell(has_edge, cached, eng, ins, outs, scr):
-    (idx_ref, coef_ref, x_ref, mask_ref, _live, _w0, bg_ref, eagg_ref,
-     _wx, _wh, _bp) = ins
-    out_ref = outs[0]
-    xa, xb = scr[1], scr[2]
-    l, j = eng.l, eng.j
-    d_pad = xa.shape[1]
+def _gcn_layer(has_edge, eng, idx_ref, coef_ref, x_ref, mask_ref, eagg_ref,
+               xact, cagg, w_blk, bias, out_ref):
+    l = eng.l
+    rows = eng.rows
 
-    # layer-0 activations are this step's node features: (re)load the ping
-    # buffer at the first program of every step.
-    @pl.when(jnp.logical_and(l == 0, jnp.logical_and(eng.first_dblock,
-                                                     j == 0)))
+    @pl.when(jnp.logical_and(l == 0, eng.step_start))
     def _init_x():
-        xa[...] = x_ref[0, 0]
+        xact[0] = x_ref[0, 0]
 
-    leven = (l % 2) == 0  # even layers read A / write B, odd the reverse
-    idx, coef = idx_ref[0, 0], coef_ref[0, 0]
-    mask = mask_ref[0, 0][:, None]
-    tn, k = idx.shape
-    rows = pl.ds(j * tn, tn)
+    src = l % 2
 
     def _aggregate():
-        x_prev = jnp.where(leven, xa[...], xb[...])
-        g = jnp.take(x_prev, idx.reshape(-1),
-                     axis=0).reshape(tn, k, d_pad)
-        out = (g * coef[..., None]).sum(axis=1)
+        a = _ell_matrix(idx_ref[0, 0], coef_ref[0, 0], xact.shape[1])
+        out = _dot_exact(a, xact[src])
         return out + eagg_ref[0, 0, 0] if has_edge else out
 
-    if cached:  # D > 1: aggregate once per (t, l, j); d > 0 re-reads
-        cagg = scr[3]
-
+    if cagg is not None:  # D > 1: aggregate once per (t, l, j); d > 0 re-reads
         @pl.when(eng.first_dblock)
         def _fill_cache():
             cagg[rows] = _aggregate()
 
         agg = cagg[rows]
-    else:       # single d block: inline, no scratch round-trip
+    else:                 # single d block: inline, no scratch round-trip
         agg = _aggregate()
 
-    w_blk = eng.state_block(scr, 0)                     # (d_pad, td)
-    h = agg @ w_blk + bg_ref[0][None, :]
-    h = jnp.where(l == eng.n_layers - 1, h, jnp.maximum(h, 0.0)) * mask
-
-    @pl.when(jnp.logical_not(leven))
-    def _wr_a():
-        xa[rows, eng.blk] = h
-
-    @pl.when(leven)
-    def _wr_b():
-        xb[rows, eng.blk] = h
+    h = _dot_exact(agg, w_blk) + bias
+    h = jnp.where(l == eng.n_layers - 1, h, jnp.maximum(h, 0.0)) * mask_ref[0, 0]
+    xact[1 - src, rows, eng.blk] = h
 
     # model output = last layer's (masked, linear) activations
     @pl.when(l == eng.n_layers - 1)
     def _out():
         out_ref[0, 0] = h
+
+
+def _layer_specs(n: int, tn: int, k: int, d_pad: int, has_edge: bool):
+    """(idx, coef, node_feat, mask) specs of the layer-sequenced families,
+    plus the edge-term spec: per-layer (B, T, L, n_pad, d_pad) blocks, or
+    one pinned dummy block."""
+    eagg_map = ((lambda bi, t, l, d, j: (bi, t, l, j, 0)) if has_edge
+                else (lambda bi, t, l, d, j: (0, 0, 0, 0, 0)))
+    return ([_tile_spec(tn, k), _tile_spec(tn, k), _step_spec(n, d_pad),
+             _tile_spec(tn, 1)],
+            pl.BlockSpec((1, 1, 1, tn, d_pad), eagg_map))
+
+
+# ------------------------------------------------------------------------
+# EvolveGCN: weights-resident family. No node-resident recurrent state —
+# the recurrence is over the per-layer GCN weights W_l^t, evolved by a
+# matrix-GRU between snapshots (live-gated by the engine). The d axis
+# blocks W's COLUMNS, which the matrix-GRU evolves independently (columns
+# are the GRU batch), so per-(l, d-block) evolution is exact. Padding
+# convention: all widths zero-padded into a common square d_pad; GRU
+# params padded PER GATE BLOCK (ops._pad_matrix_gru_params); zero-padded
+# weight ROWS stay zero under evolution per block (their gate inputs are
+# identically 0), keeping junk activation columns out of valid output
+# columns.
+
+def _evolve_cell(has_edge, cached, eng, ins, outs, scr):
+    (idx_ref, coef_ref, x_ref, mask_ref, _live, _w0, bg_ref, eagg_ref,
+     _wx, _wh, _bp) = ins
+    _gcn_layer(has_edge, eng, idx_ref, coef_ref, x_ref, mask_ref, eagg_ref,
+               scr[1], scr[2] if cached else None,
+               eng.state_block(scr, 0), bg_ref[0], outs[0])
 
 
 def _evolve_evolve(eng, ins, scr):
@@ -1254,8 +1348,8 @@ def _evolve_evolve(eng, ins, scr):
     wx_ref, wh_ref, bp_ref = ins[8], ins[9], ins[10]
     wt = eng.state_block(scr, 0).T                     # (td, d_pad)
     d = wt.shape[1]
-    gx = wt @ wx_ref[0] + bp_ref[0][None, :]
-    gh = wt @ wh_ref[0]
+    gx = _dot_exact(wt, wx_ref[0]) + bp_ref[0]
+    gh = _dot_exact(wt, wh_ref[0])
     rx, zx, nx = gx[:, :d], gx[:, d:2 * d], gx[:, 2 * d:]
     rh, zh, nh = gh[:, :d], gh[:, d:2 * d], gh[:, 2 * d:]
     r = jax.nn.sigmoid(rx + rh)
@@ -1283,24 +1377,12 @@ def _evolve_build(neigh_idx, neigh_coef, node_feat, node_mask, live,
     cached = D > 1 or paged
     grid = (B, T, L, D, n // tn)
 
-    tile = lambda bi, t, l, d, j: (bi, t, j, 0)
-    step = lambda bi, t, l, d, j: (bi, t, 0, 0)
-    row = lambda bi, t, l, d, j: (bi, t, j)
-    flag = lambda bi, t, l, d, j: (bi, t)
-    w_in = lambda bi, t, l, d, j: (bi, l, 0, 0)
-    w_out = lambda bi, t, l, d, j: (bi, l, 0, d)
-    out_tile = lambda bi, t, l, d, j: (bi, t, j, d)
-    layer_res3 = lambda bi, t, l, d, j: (l, 0, 0)
-    layer_blk = lambda bi, t, l, d, j: (l, d)
-
     has_edge = edge_agg is not None
-    if has_edge:
-        eagg_map = lambda bi, t, l, d, j: (bi, t, l, j, 0)
-    else:
+    if not has_edge:
         # one pinned (revisited) dummy block instead of (B,T,L,n,d_pad)
         # of streamed zeros; the kernel never reads it.
         edge_agg = jnp.zeros((1, 1, 1, tn, d_pad), node_feat.dtype)
-        eagg_map = lambda bi, t, l, d, j: (0, 0, 0, 0, 0)
+    node_specs, eagg_spec = _layer_specs(n, tn, k, d_pad, has_edge)
 
     if paged:
         # HBM-resident evolving W, evolved IN PLACE in the aliased
@@ -1308,43 +1390,45 @@ def _evolve_build(neigh_idx, neigh_coef, node_feat, node_mask, live,
         # block into a (d_pad, td) staging window, the evolve hook updates
         # staging, write-back pushes it home. No read ring: the cell only
         # ever consumes its own (l, d) block, never the full width.
-        w_in_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        w_out_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        w_in_spec = pl.BlockSpec(memory_space=pl.ANY)
+        w_out_spec = pl.BlockSpec(memory_space=pl.ANY)
         states = (_StateMeta("weights", in_idx=5, out_idx=1, scr_idx=0,
-                             sem_idx=4),)
+                             sem_idx=3),)
         state_scratch = [pltpu.VMEM((d_pad, td), w0.dtype)]  # W staging
         sem_scratch = [pltpu.SemaphoreType.DMA((depth + 1,))]
         aliases = {5: 1}
     else:
-        w_in_spec = pl.BlockSpec((1, 1, d_pad, d_pad), w_in)
-        w_out_spec = pl.BlockSpec((1, 1, d_pad, td), w_out)
+        w_in_spec = pl.BlockSpec((1, 1, d_pad, d_pad),
+                                 lambda bi, t, l, d, j: (bi, l, 0, 0))
+        w_out_spec = pl.BlockSpec((1, 1, d_pad, td),
+                                  lambda bi, t, l, d, j: (bi, l, 0, d))
         states = (_StateMeta("weights", in_idx=5, out_idx=1, scr_idx=0),)
         state_scratch = [pltpu.VMEM((L, d_pad, d_pad), w0.dtype)]
         sem_scratch = []
         aliases = {}
 
+    layer_res3 = lambda bi, t, l, d, j: (l, 0, 0)
     meta = _Meta(
-        n_in=11, n_out=2, states=states,
-        live_idx=4, td=td, paged=paged, depth=depth, g_rows=0)
+        n_in=11, n_out=2, states=states, live_idx=4, td=td, tn=tn,
+        n_dblocks=D, paged=paged, depth=depth, g_rows=0)
     return _Launch(
         grid=grid,
-        inputs=(neigh_idx, neigh_coef, node_feat, node_mask, live,
-                w0, b_gcn, edge_agg, gru_wx, gru_wh, gru_b),
+        inputs=(neigh_idx, neigh_coef, node_feat, node_mask[..., None],
+                live, w0, b_gcn[:, None], edge_agg, gru_wx, gru_wh,
+                gru_b[:, None]),
         in_specs=[
-            pl.BlockSpec((1, 1, tn, k), tile),            # neigh_idx (local)
-            pl.BlockSpec((1, 1, tn, k), tile),            # neigh_coef
-            pl.BlockSpec((1, 1, n, d_pad), step),         # node_feat
-            pl.BlockSpec((1, 1, tn), row),                # node_mask
-            pl.BlockSpec((1, 1), flag),                   # live flag
+            *node_specs,                                  # idx/coef/x/mask
+            pl.BlockSpec(memory_space=pltpu.SMEM),        # live flags (B, T)
             w_in_spec,                                    # W0, per (b, l)
-            pl.BlockSpec((1, td), layer_blk),             # GCN bias tile
-            pl.BlockSpec((1, 1, 1, tn, d_pad), eagg_map),  # edge agg
+            pl.BlockSpec((1, 1, td),                      # GCN bias tile
+                         lambda bi, t, l, d, j: (l, 0, d)),
+            eagg_spec,                                    # edge agg
             pl.BlockSpec((1, d_pad, 3 * d_pad), layer_res3),  # GRU wx
             pl.BlockSpec((1, d_pad, 3 * d_pad), layer_res3),  # GRU wh
-            pl.BlockSpec((1, 3 * d_pad), lambda bi, t, l, d, j: (l, 0)),
+            pl.BlockSpec((1, 1, 3 * d_pad), layer_res3),      # GRU bias
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tn, td), out_tile),       # per-step outputs
+            _out_tile_spec(tn, td),                       # per-step outputs
             w_out_spec,                                   # final weights
         ],
         out_shape=[
@@ -1352,10 +1436,9 @@ def _evolve_build(neigh_idx, neigh_coef, node_feat, node_mask, live,
             jax.ShapeDtypeStruct((B, L, d_pad, d_pad), w0.dtype),
         ],
         scratch=state_scratch + [
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # activation ping
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # activation pong
+            pltpu.VMEM((2, n, d_pad), node_feat.dtype),   # activation planes
         ] + ([
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # aggregation cache
+            pltpu.VMEM((n, d_pad), node_feat.dtype),      # aggregation cache
         ] if cached else []) + sem_scratch,
         meta=meta,
         cell=functools.partial(_evolve_cell, has_edge, cached),
@@ -1378,81 +1461,70 @@ def _evolve_build(neigh_idx, neigh_coef, node_feat, node_mask, live,
 # lanes contribute exactly zero to both aggregations, whatever timestamp
 # they carry — the property tests pin this.
 
+_TGN_MLOC, _TGN_CINP = 1, 3                  # scratch slots (both residencies)
+
+
 def _tgn_cell(cached, eng, ins, outs, scr):
-    (gidx_ref, coef_ref, ts_ref, x_ref, rowg_ref, mask_ref, _m0,
+    (idx_ref, coef_ref, ts_ref, x_ref, _rowg, mask_ref, _m0,
      freq_ref, win_ref, wx_ref, wh_ref, b_ref) = ins
     out_ref = outs[0]
+    mloc = scr[_TGN_MLOC]
+    mask = mask_ref[0, 0]
+    rows = eng.rows
 
-    gidx, coef, ts = gidx_ref[0, 0], coef_ref[0, 0], ts_ref[0, 0]
-    rowg = rowg_ref[0, 0]
-    mask = mask_ref[0, 0][:, None]
-    tn = gidx.shape[0]
-    rows = pl.ds(eng.j * tn, tn)
-    n_global = eng.g_rows
-    row_safe = jnp.where(rowg < n_global, rowg, 0)
+    @pl.when(eng.step_start)
+    def _gather():
+        eng.gather_step(0, mloc)                # t-1 memory of touched nodes
 
     def _inputs():
+        idx, coef, ts = idx_ref[0, 0], coef_ref[0, 0], ts_ref[0, 0]
         # sinusoidal time encoding per event lane; padded freq columns
         # give cos(0)=1 but only ever multiply zero-padded wx rows
-        enc = jnp.cos(ts[..., None] * freq_ref[0][None, None, :])
-        agg_e = (enc * coef[..., None]).sum(axis=1)
-        x_tile = jax.lax.dynamic_slice_in_dim(x_ref[0, 0], eng.j * tn, tn,
-                                              axis=0)
-        return x_tile @ win_ref[...], agg_e
+        freq = freq_ref[...]                    # (1, d_pad)
+
+        def _encode(kk, acc):
+            return acc + _lane(coef, kk) * jnp.cos(_lane(ts, kk) * freq)
+
+        agg_e = jax.lax.fori_loop(
+            0, idx.shape[1], _encode,
+            jnp.zeros((idx.shape[0], freq.shape[1]), jnp.float32))
+        a = _ell_matrix(idx, coef, mloc.shape[0])
+        xw = _dot_exact(x_ref[0, 0, rows, :], win_ref[...])
+        return (xw + _dot_exact(a, mloc[...])) + agg_e
 
     if cached:  # D > 1 or paged: compute once per (t, j); d > 0 re-reads
-        cinp, cmem = scr[2], scr[3]
+        cinp = scr[_TGN_CINP]
 
         @pl.when(eng.first_dblock)
-        def _fill_caches():
-            xw, agg_e = _inputs()
-            if eng.paged:
-                # sweep the t-1 memory's windows through the DMA ring;
-                # every term is columnwise and the sum association
-                # ((x@win + agg_m) + agg_e) matches the resident fill,
-                # so per-window fills are bit-identical
-                def _one(w, wblk, sval):
-                    agg_m = _agg_store(gidx, coef, sval)
-                    cols = slice(w * eng.td, (w + 1) * eng.td)
-                    cinp[rows, wblk] = (xw[:, cols] + agg_m) + agg_e[:, cols]
-                    cmem[rows, wblk] = jnp.take(sval, row_safe,
-                                                axis=0) * mask
+        def _fill_cache():
+            cinp[rows] = _inputs()
 
-                eng.paged_fill(0, _one)
-            else:
-                store = eng.state_read(scr, 0)   # full-width t-1 memory
-                cinp[rows] = (xw + _agg_store(gidx, coef, store)) + agg_e
-                cmem[rows] = jnp.take(store, row_safe, axis=0) * mask
-
-        inp, mem_own = cinp[rows], cmem[rows]
+        inp = cinp[rows]
     else:       # single d block: inline, no scratch round-trip
-        store = eng.state_read(scr, 0)           # full-width t-1 memory
-        xw, agg_e = _inputs()
-        inp = (xw + _agg_store(gidx, coef, store)) + agg_e
-        mem_own = jnp.take(store, row_safe, axis=0) * mask
+        inp = _inputs()
 
     td = eng.td
-    gx = inp @ wx_ref[0] + b_ref[0][None, :]
-    gh = mem_own @ wh_ref[0]
+    gx = _dot_exact(inp, wx_ref[0]) + b_ref[0]
+    gh = _dot_exact(mloc[rows] * mask, wh_ref[0])
     rx, zx, nx = gx[:, :td], gx[:, td:2 * td], gx[:, 2 * td:]
     rh, zh, nh = gh[:, :td], gh[:, td:2 * td], gh[:, 2 * td:]
     r = jax.nn.sigmoid(rx + rh)
     z = jax.nn.sigmoid(zx + zh)
     nn = jnp.tanh(nx + r * nh)
-    m_new = ((1.0 - z) * nn + z * eng.dslice(mem_own)) * mask
-
-    eng.state_scatter(scr, 0, rowg, m_new)
+    m_own = mloc[rows, eng.blk] * mask
+    m_new = ((1.0 - z) * nn + z * m_own) * mask
+    eng.scatter_tile(0, m_new)
     out_ref[0, 0] = m_new
 
 
-def _tgn_build(neigh_gidx, neigh_coef, neigh_ts, node_feat, row_gidx,
+def _tgn_build(neigh_idx, neigh_coef, neigh_ts, node_feat, row_gidx,
                node_mask, mem0, freq, w_in, wx, wh, b, *,
                tn: int, td: Optional[int], residency: str = "vmem",
                depth: int = 2):
-    """Event-stream launch: (B, T, n, k) ELL event batches with per-lane
-    timestamps; the node-memory store (B, G, h) is the single pingpong
-    state, entering and leaving the chip once per stream."""
-    B, T, n, k = neigh_gidx.shape
+    """Event-stream launch: (B, T, n, k) ELL event batches (local partner
+    ids) with per-lane timestamps; the node-memory store (B, G, h) is the
+    single pingpong state, entering and leaving the chip once per stream."""
+    B, T, n, k = neigh_idx.shape
     din, h = node_feat.shape[3], mem0.shape[2]
     G = mem0.shape[1]
     assert n % tn == 0
@@ -1463,85 +1535,66 @@ def _tgn_build(neigh_gidx, neigh_coef, neigh_ts, node_feat, row_gidx,
     cached = D > 1 or paged
     grid = (B, T, 1, D, n // tn)
 
-    mem0p = _pad_dim(mem0, d_pad, -1)
     freq_p = _pad_dim(freq, d_pad, 0)[None]           # (1, d_pad): 2-D ref
     win_p = _pad_dim(w_in, d_pad, -1)
     wxp = _pack_gate_blocks(_pad_dim(wx, d_pad, 0), 3, td)  # (D, d_pad, 3td)
     whp = _pack_gate_blocks(_pad_dim(wh, d_pad, 0), 3, td)  # (D, d_pad, 3td)
-    bp = _pack_gate_bias(b, 3, td)                          # (D, 3td)
+    bp = _pack_gate_bias(b, 3, td)                          # (D, 1, 3td)
+    m_in, m_in_spec, m_out_spec, m_out_shape = _node_state_io(
+        mem0, d_pad, td, True, paged)
 
-    tile = lambda bi, t, l, d, j: (bi, t, j, 0)
-    step = lambda bi, t, l, d, j: (bi, t, 0, 0)
-    row = lambda bi, t, l, d, j: (bi, t, j)
-    state_in = lambda bi, t, l, d, j: (bi, 0, 0)
-    state_out = lambda bi, t, l, d, j: (bi, 0, d)
-    out_tile = lambda bi, t, l, d, j: (bi, t, j, d)
-    res2 = lambda bi, t, l, d, j: (0, 0)
-    dblk = lambda bi, t, l, d, j: (d, 0, 0)
-    dblk1 = lambda bi, t, l, d, j: (d, 0)
-
+    tables = [
+        pltpu.VMEM((n, d_pad), mem0.dtype),           # t-1 memory row table
+        pltpu.VMEM((tn, td), mem0.dtype),             # row-staging tile
+    ] + ([
+        pltpu.VMEM((n, d_pad), node_feat.dtype),      # GRU-input cache
+    ] if cached else [])
     if paged:
         # HBM-resident memory store as an A/B plane pair, aliased in-place
-        # onto its output; caches stay at the resident positions (2, 3).
-        m_in = jnp.stack([mem0p, jnp.zeros_like(mem0p)], axis=1)
-        m_in_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        m_out_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        m_out_shape = jax.ShapeDtypeStruct((B, 2, G, d_pad), mem0.dtype)
+        # onto its output.
         states = (_StateMeta("pingpong", in_idx=6, out_idx=1, scr_idx=0,
-                             ring_idx=1, sem_idx=4),)
-        state_scratch = [
-            pltpu.VMEM((G, td), mem0.dtype),            # mem staging window
-            pltpu.VMEM((depth, G, td), mem0.dtype),     # mem read ring
+                             ring_idx=4, sem_idx=5),)
+        scratch = [pltpu.VMEM((G, td), mem0.dtype)] + tables + [  # staging
+            pltpu.VMEM((depth, G, td), mem0.dtype),               # read ring
+            pltpu.SemaphoreType.DMA((depth + 1,)),
         ]
-        sem_scratch = [pltpu.SemaphoreType.DMA((depth + 1,))]
         aliases = {6: 1}
     else:
-        m_in = mem0p
-        m_in_spec = pl.BlockSpec((1, G, d_pad), state_in)
-        m_out_spec = pl.BlockSpec((1, G, td), state_out)
-        m_out_shape = jax.ShapeDtypeStruct((B, G, d_pad), mem0.dtype)
         states = (_StateMeta("pingpong", in_idx=6, out_idx=1, scr_idx=0),)
-        state_scratch = [
-            pltpu.VMEM((G, d_pad), mem0.dtype),       # mem ping
-            pltpu.VMEM((G, d_pad), mem0.dtype),       # mem pong
-        ]
-        sem_scratch = []
+        scratch = [pltpu.VMEM((2, G, d_pad), mem0.dtype)] + tables  # planes
         aliases = {}
 
+    res2 = lambda bi, t, l, d, j: (0, 0)
     meta = _Meta(
-        n_in=12, n_out=2, states=states,
-        live_idx=None, td=td, temporal="event", paged=paged, depth=depth,
-        g_rows=G)
+        n_in=12, n_out=2, states=states, live_idx=None, td=td, tn=tn,
+        n_dblocks=D, temporal="event", paged=paged, depth=depth, g_rows=G,
+        rows_idx=4, stage_idx=2)
     return _Launch(
         grid=grid,
-        inputs=(neigh_gidx, neigh_coef, neigh_ts, node_feat, row_gidx,
-                node_mask, m_in, freq_p, win_p, wxp, whp, bp),
+        inputs=(neigh_idx, neigh_coef, neigh_ts, node_feat,
+                row_gidx[:, :, None, :], node_mask[..., None], m_in, freq_p,
+                win_p, wxp, whp, bp),
         in_specs=[
-            pl.BlockSpec((1, 1, tn, k), tile),        # partner gidx (global)
-            pl.BlockSpec((1, 1, tn, k), tile),        # event coef (1/deg)
-            pl.BlockSpec((1, 1, tn, k), tile),        # event timestamps
-            pl.BlockSpec((1, 1, n, din), step),       # touched-node features
-            pl.BlockSpec((1, 1, tn), row),            # row_gidx
-            pl.BlockSpec((1, 1, tn), row),            # node_mask
+            _tile_spec(tn, k),                        # partner ids (local)
+            _tile_spec(tn, k),                        # event coef (1/deg)
+            _tile_spec(tn, k),                        # event timestamps
+            _step_spec(n, din),                       # touched-node features
+            _row_ids_spec(n),                         # global row ids (SMEM)
+            _tile_spec(tn, 1),                        # node_mask column
             m_in_spec,                                # mem0 / mem plane pair
             pl.BlockSpec((1, d_pad), res2),           # time-enc frequencies
             pl.BlockSpec((din, d_pad), res2),         # input projection
-            pl.BlockSpec((1, d_pad, 3 * td), dblk),   # wx gate tile, per d
-            pl.BlockSpec((1, d_pad, 3 * td), dblk),   # wh gate tile, per d
-            pl.BlockSpec((1, 3 * td), dblk1),         # bias gate tile
+            *_gate_specs(d_pad, 3, td, d_pad),        # wx / wh / bias tiles
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tn, td), out_tile),   # per-batch mem outputs
+            _out_tile_spec(tn, td),                   # per-batch mem outputs
             m_out_spec,                               # final memory
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, n, d_pad), node_feat.dtype),
             m_out_shape,
         ],
-        scratch=state_scratch + ([
-            pltpu.VMEM((n, d_pad), node_feat.dtype),  # GRU-input cache
-            pltpu.VMEM((n, d_pad), mem0.dtype),       # own-row mem cache
-        ] if cached else []) + sem_scratch,
+        scratch=scratch,
         meta=meta,
         cell=functools.partial(_tgn_cell, cached),
         evolve=None,
@@ -1555,62 +1608,15 @@ def _tgn_build(neigh_gidx, neigh_coef, neigh_ts, node_feat, row_gidx,
 # init/copy-forward/drain loops are vacuously empty. T must be 1:
 # independent snapshots fold onto the B axis instead (the serve express
 # lane), so a "stream" of static graphs is just a batch. The L grid axis
-# sequences the multi-layer GCN over the evolve-style activation ping-pong
-# scratch, but the per-layer weights come straight from INPUT refs
+# sequences the multi-layer GCN over the shared activation-plane layer
+# body, but the per-layer weights come straight from INPUT refs
 # (BlockSpec-indexed by (l, d)) — nothing is resident across steps.
 
 def _static_cell(has_edge, cached, eng, ins, outs, scr):
     (idx_ref, coef_ref, x_ref, mask_ref, w_ref, bg_ref, eagg_ref) = ins
-    out_ref = outs[0]
-    xa, xb = scr[0], scr[1]
-    l, j = eng.l, eng.j
-    d_pad = xa.shape[1]
-
-    # layer-0 activations are the snapshot's node features
-    @pl.when(jnp.logical_and(l == 0, jnp.logical_and(eng.first_dblock,
-                                                     j == 0)))
-    def _init_x():
-        xa[...] = x_ref[0, 0]
-
-    leven = (l % 2) == 0  # even layers read A / write B, odd the reverse
-    idx, coef = idx_ref[0, 0], coef_ref[0, 0]
-    mask = mask_ref[0, 0][:, None]
-    tn, k = idx.shape
-    rows = pl.ds(j * tn, tn)
-
-    def _aggregate():
-        x_prev = jnp.where(leven, xa[...], xb[...])
-        g = jnp.take(x_prev, idx.reshape(-1),
-                     axis=0).reshape(tn, k, d_pad)
-        out = (g * coef[..., None]).sum(axis=1)
-        return out + eagg_ref[0, 0, 0] if has_edge else out
-
-    if cached:  # D > 1: aggregate once per (l, j); d > 0 re-reads
-        cagg = scr[2]
-
-        @pl.when(eng.first_dblock)
-        def _fill_cache():
-            cagg[rows] = _aggregate()
-
-        agg = cagg[rows]
-    else:       # single d block: inline, no scratch round-trip
-        agg = _aggregate()
-
-    h = agg @ w_ref[0] + bg_ref[0][None, :]
-    h = jnp.where(l == eng.n_layers - 1, h, jnp.maximum(h, 0.0)) * mask
-
-    @pl.when(jnp.logical_not(leven))
-    def _wr_a():
-        xa[rows, eng.blk] = h
-
-    @pl.when(leven)
-    def _wr_b():
-        xb[rows, eng.blk] = h
-
-    # model output = last layer's (masked, linear) activations
-    @pl.when(l == eng.n_layers - 1)
-    def _out():
-        out_ref[0, 0] = h
+    _gcn_layer(has_edge, eng, idx_ref, coef_ref, x_ref, mask_ref, eagg_ref,
+               scr[0], scr[1] if cached else None, w_ref[0], bg_ref[0],
+               outs[0])
 
 
 def _static_build(neigh_idx, neigh_coef, node_feat, node_mask,
@@ -1636,48 +1642,37 @@ def _static_build(neigh_idx, neigh_coef, node_feat, node_mask,
     D = d_pad // td
     grid = (B, 1, L, D, n // tn)
 
-    tile = lambda bi, t, l, d, j: (bi, t, j, 0)
-    step = lambda bi, t, l, d, j: (bi, t, 0, 0)
-    row = lambda bi, t, l, d, j: (bi, t, j)
-    out_tile = lambda bi, t, l, d, j: (bi, t, j, d)
-    layer_wblk = lambda bi, t, l, d, j: (l, 0, d)
-    layer_blk = lambda bi, t, l, d, j: (l, d)
-
     has_edge = edge_agg is not None
-    if has_edge:
-        eagg_map = lambda bi, t, l, d, j: (bi, t, l, j, 0)
-    else:
+    if not has_edge:
         # one pinned (revisited) dummy block; the kernel never reads it.
         edge_agg = jnp.zeros((1, 1, 1, tn, d_pad), node_feat.dtype)
-        eagg_map = lambda bi, t, l, d, j: (0, 0, 0, 0, 0)
+    node_specs, eagg_spec = _layer_specs(n, tn, k, d_pad, has_edge)
 
     meta = _Meta(
-        n_in=7, n_out=1, states=(),
-        live_idx=None, td=td, temporal="static")
+        n_in=7, n_out=1, states=(), live_idx=None, td=td, tn=tn,
+        n_dblocks=D, temporal="static")
     return _Launch(
         grid=grid,
-        inputs=(neigh_idx, neigh_coef, node_feat, node_mask,
-                weights, b_gcn, edge_agg),
+        inputs=(neigh_idx, neigh_coef, node_feat, node_mask[..., None],
+                weights, b_gcn[:, None], edge_agg),
         in_specs=[
-            pl.BlockSpec((1, 1, tn, k), tile),            # neigh_idx (local)
-            pl.BlockSpec((1, 1, tn, k), tile),            # neigh_coef
-            pl.BlockSpec((1, 1, n, d_pad), step),         # node_feat
-            pl.BlockSpec((1, 1, tn), row),                # node_mask
-            pl.BlockSpec((1, d_pad, td), layer_wblk),     # W_l column block
-            pl.BlockSpec((1, td), layer_blk),             # GCN bias tile
-            pl.BlockSpec((1, 1, 1, tn, d_pad), eagg_map),  # edge agg
+            *node_specs,                                  # idx/coef/x/mask
+            pl.BlockSpec((1, d_pad, td),                  # W_l column block
+                         lambda bi, t, l, d, j: (l, 0, d)),
+            pl.BlockSpec((1, 1, td),                      # GCN bias tile
+                         lambda bi, t, l, d, j: (l, 0, d)),
+            eagg_spec,                                    # edge agg
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, tn, td), out_tile),       # per-snapshot outs
+            _out_tile_spec(tn, td),                       # per-snapshot outs
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, 1, n, d_pad), node_feat.dtype),
         ],
         scratch=[
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # activation ping
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # activation pong
+            pltpu.VMEM((2, n, d_pad), node_feat.dtype),   # activation planes
         ] + ([
-            pltpu.VMEM((n, d_pad), node_feat.dtype),   # aggregation cache
+            pltpu.VMEM((n, d_pad), node_feat.dtype),      # aggregation cache
         ] if D > 1 else []),
         meta=meta,
         cell=functools.partial(_static_cell, has_edge, D > 1),

@@ -1,5 +1,9 @@
 import os
+# CPU-only tool: 512 host-platform devices, never the accelerator (a
+# process holding the chip would lock every other process off it);
+# --all children inherit both settings through os.environ.
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -7,6 +7,9 @@ are what the multi-pod dry-run must prove out.
 
 Functions, not module constants: importing this module never touches jax
 device state (smoke tests see 1 CPU device; only dryrun.py forces 512).
+The model-parallel meshes use Auto axes: the models place activations
+with ``with_sharding_constraint``, which ``jax.make_mesh``'s default
+Explicit axes reject.
 """
 from __future__ import annotations
 
@@ -14,7 +17,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 @dataclass(frozen=True)
@@ -55,9 +58,11 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"need {need} devices for mesh {shape}, have {len(devs)} — "
             "run under dryrun.py (XLA_FLAGS=--xla_force_host_platform_device_count=512)")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> Mesh:
     """1x1 mesh on the real local device (smoke tests / examples)."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1],
+                         axis_types=(AxisType.Auto,) * 2)

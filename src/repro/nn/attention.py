@@ -151,7 +151,6 @@ def flash_attention(q, k, v, *, causal: bool, bq: int = 512, bk: int = 512):
     """Pallas flash kernel, shard_map'd over (batch, heads) when a mesh is
     active. q (B,S,Hq,hd), k/v (B,S,Hkv,hd); heads kv-major like the
     grouped-einsum path."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.api import current_mesh
@@ -177,8 +176,8 @@ def flash_attention(q, k, v, *, causal: bool, bq: int = 512, bk: int = 512):
     bspec = batch_axes if (batch_axes and q.shape[0] % _axes_size(mesh, batch_axes) == 0) else None
     hspec = "model" if "model" in mesh.axis_names and q.shape[2] % _axes_size(mesh, ("model",)) == 0 else None
     qs = P(bspec, None, hspec, None)
-    return shard_map(local, mesh=mesh, in_specs=(qs, qs, qs), out_specs=qs,
-                     check_rep=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(qs, qs, qs),
+                         out_specs=qs, check_vma=False)(q, k, v)
 
 
 def _axes_size(mesh, axes) -> int:

@@ -188,8 +188,6 @@ def moe_block(p: dict, cfg: ModelConfig, x: jax.Array, *,
     tp = _mesh_axis_size(mesh, "model")
     use_ep = (impl == "ep") or (impl == "auto" and tp > 1)
     if use_ep:
-        from jax.experimental.shard_map import shard_map
-
         e_pad = p["w_gate"].shape[0]
         b, s, d = x.shape
         dp = _mesh_axis_size(mesh, "data") * _mesh_axis_size(mesh, "pod")
@@ -205,12 +203,12 @@ def moe_block(p: dict, cfg: ModelConfig, x: jax.Array, *,
         body = functools.partial(
             _ep_body, cfg=cfg, tp=tp, e_pad=e_pad,
             cap_send=cap_send, cap_local=cap_local, fsdp=fsdp)
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, P(None, None), w_spec, w_spec,
                       P("model", None, "data" if fsdp else None)),
             out_specs=x_spec,
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     else:
         y = _moe_dense(p, cfg, x)

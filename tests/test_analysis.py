@@ -79,7 +79,7 @@ def test_dma_ring_order_fires(monkeypatch):
             dmas[w] = dma
         for w in range(n_win):
             dmas.pop(w).wait()
-            fill(w, pl.ds(w * self.td, self.td), ring[w % depth])
+            fill(w, pl.ds(w * self.td, self.td), ring.at[w % depth])
 
     monkeypatch.setattr(stream_fused._Engine, "paged_fill", bad_paged_fill)
     findings = contracts.run_contracts(
@@ -234,6 +234,30 @@ def test_jnp_in_kernel_body_fires(tmp_path):
             o_ref[...] = jnp.concatenate([x_ref[...], x_ref[...]])
         """, "jnp-in-kernel-body")
     assert "jnp.concatenate" in f.message and f.severity == "warning"
+
+
+@pytest.mark.parametrize("body", [
+    "def _x_cell(eng, ins, outs, scr):\n"
+    "    return jnp.take(ins[0][...], ins[1][...], axis=0)\n",
+    "class _Engine:\n"
+    "    def scatter(self, store, rows, val):\n"
+    "        return store.at[rows].set(val, mode='drop')\n",
+])
+def test_gather_scatter_in_stream_engine_fires(tmp_path, body):
+    """The stream engine's kernel bodies (cell hooks, ``_Engine``
+    methods) may not gather or ``.at[]``-update a value."""
+    f = _lint_one(tmp_path, "src/repro/kernels/stream_fused.py", body,
+                  "jnp-in-kernel-body")
+    assert "take" in f.message or ".at[...].set" in f.message
+
+
+def test_gather_outside_stream_engine_allowed(tmp_path):
+    """jnp.take stays legal in other kernel modules' bodies."""
+    rel = _snippet(tmp_path, "src/repro/kernels/other.py", """\
+        def _x_cell(eng, ins, outs, scr):
+            return jnp.take(ins[0][...], ins[1][...], axis=0)
+        """)
+    assert lint.run_lint(tmp_path, files=[rel]) == []
 
 
 def test_jnp_outside_kernel_body_allowed(tmp_path):

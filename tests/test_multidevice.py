@@ -55,15 +55,15 @@ def test_moe_ep_equals_dense():
 def test_compressed_psum_error_feedback():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, functools
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum
-        mesh = jax.make_mesh((8,), ('data',))
+        mesh = jax.make_mesh((8,), ('data',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         def body(x, r):
             return compressed_psum(x, r, 'data')
-        f = jax.jit(shard_map(body, mesh=mesh,
+        f = jax.jit(jax.shard_map(body, mesh=mesh,
                     in_specs=(P('data'), P('data')), out_specs=(P('data'), P('data')),
-                    check_rep=False))
+                    check_vma=False))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(8, 1024)).astype(np.float32))
         r = jnp.zeros_like(x)
@@ -92,7 +92,8 @@ def test_mini_dryrun_8dev_mesh():
         from repro.models import RuntimeConfig
         from repro.optim import AdamWConfig
         from repro.roofline import collective_bytes, cost_analysis_dict
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = reduce_for_smoke(ARCHS['qwen3-32b'])
         rt = RuntimeConfig(tp=4, scan_layers=False, attn_chunk=64, moe_impl='ep', loss_chunk=16)
         shape = ShapeConfig('mini_train', 64, 8, 'train')

@@ -70,12 +70,12 @@ def _dims(family, box):
     a, kw = box["args"], box["kw"]
     td = kw["td"]
     if family == "gcrn":
-        n, din, h0 = a[0].shape[2], a[4].shape[3], a[7]
+        n, din, h0 = a[0].shape[2], a[2].shape[3], a[5]
         G, h = h0.shape[1], h0.shape[2]
         return dict(g_rows=G, n_pad=n, din=din,
                     d_pad=stream_fused._round_up(h, td or h))
     if family == "stacked":
-        n, h0, w_gcn = a[0].shape[2], a[6], a[7]
+        n, h0, w_gcn = a[0].shape[2], a[5], a[6]
         G, h = h0.shape[1], h0.shape[2]
         return dict(g_rows=G, n_pad=n, dmid=w_gcn.shape[1],
                     d_pad=stream_fused._round_up(h, td or h))
@@ -191,8 +191,8 @@ def test_scratch_byte_accounting(family, residency, td, depth):
         ops.stream_steps(family, *args, tn=32, td=td, **kw)
         actual = stream_fused.launch_scratch_bytes(box["launch"])
         est = stream_fused.stream_vmem_bytes(
-            family, td=td, residency=residency, depth=depth,
-            **_dims(family, box))
+            family, td=td, tn=box["kw"]["tn"], residency=residency,
+            depth=depth, **_dims(family, box))
     assert actual == est, (
         f"{family}/{residency}/td={td}/depth={depth}: "
         f"assembled {actual} VMEM bytes, estimator says {est}")
