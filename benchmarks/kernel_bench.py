@@ -542,14 +542,16 @@ def run_serve_schedulers(n_backlog: int = 24, n_inc_snaps: int = 6,
         # charge a few hundred ms of CPU compile to whichever snapshot's
         # launch hits it first and poison the latency percentiles)
         from repro.core import stack_time
-        ps = srv._preprocess(tenant_snaps["backlog"][0])
+        from repro.graph.padding import stack_streams
+        ps, _ = srv._prepare(tenant_snaps["backlog"][0])
         state = srv.model.init_state(params, mode=srv.mode)
         for b_sig in (1, 2, 4):
             for t_sig in (1, 2, 4):
                 st_b = jax.tree.map(lambda *xs: jnp.stack(xs, 0),
                                     *([state] * b_sig))
                 _, out = srv._launch_ragged(
-                    params, st_b, [stack_time([ps] * t_sig)] * b_sig,
+                    params, st_b,
+                    stack_streams([stack_time([ps] * t_sig)] * b_sig),
                     np.asarray([t_sig] * b_sig, np.int32))
                 jax.block_until_ready(out)
 
@@ -571,7 +573,7 @@ def run_serve_schedulers(n_backlog: int = 24, n_inc_snaps: int = 6,
         for _ in range(repeats):
             arrivals, stats = run_once(pace=True)
             soj = [stats.commit_ms[sid][i]
-                   - (arrivals[(sid, i)] - srv._t0_run) * 1e3
+                   - (arrivals[(sid, i)] - srv._trace.t0) * 1e3
                    for sid in inc_sids for i in range(n_inc_snaps)]
             p99 = float(np.percentile(soj, 99))
             if best is None or p99 < best[0]:

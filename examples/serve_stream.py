@@ -51,8 +51,10 @@ def main():
                     rng=jax.random.PRNGKey(0))
                 _, stats = session.serve(snaps)
                 print(f"{ds.name:9s} {name:10s} {lv:8s} "
-                      f"{stats.mean_latency_ms:8.3f} ms/snapshot "
-                      f"(host prep {np.mean(stats.preprocess_ms):.3f} ms, overlapped)")
+                      f"staging {stats.stage_ms_per_snapshot:8.3f} "
+                      f"device wait {stats.device_wait_ms_per_snapshot:8.3f} "
+                      f"ms/snapshot (host prep "
+                      f"{np.mean(stats.preprocess_ms):.3f} ms, overlapped)")
 
     # batched multi-stream serving: the production throughput axis.
     # level="v3" runs ALL B streams through ONE batched stream-kernel
@@ -111,8 +113,10 @@ def main():
     dt = time.perf_counter() - t0
     served = sum(len(v) for v in outs.values())
     print(f"multi-tenant v3: {len(streams)} clients, {served} snapshots in "
-          f"{dt*1e3:.1f} ms ({stats.mean_latency_ms:.3f} ms/snapshot, "
-          f"host prep overlapped across {len(streams)} producer threads)")
+          f"{dt*1e3:.1f} ms (staging {stats.stage_ms_per_snapshot:.3f}, "
+          f"device wait {stats.device_wait_ms_per_snapshot:.3f} "
+          f"ms/snapshot, host prep overlapped across {len(streams)} "
+          f"producer threads)")
 
 
 if __name__ == "__main__":

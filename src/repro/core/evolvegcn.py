@@ -119,8 +119,9 @@ class EvolveGCN:
 
         if not self.cfg.edge_dim:
             return None
-        return [kops.edge_aggregate(snaps.neigh_coef, snaps.neigh_eidx,
-                                    snaps.edge_feat @ p["w_edge"])
+        return [kops.edge_aggregate(
+                    snaps.neigh_coef, snaps.neigh_eidx,
+                    kops.edge_project(snaps.edge_feat, p["w_edge"]))
                 for p in params["gcn"]]
 
     def _run_stream_kernel(self, params: dict, state: dict,

@@ -145,8 +145,9 @@ class StaticGCN:
         lead_n = snaps.neigh_eidx.shape[:-1]
         return [jnp.zeros((*lead_n, p["w"].shape[0]), jnp.float32)
                 if p.get("w_edge") is None
-                else kops.edge_aggregate(snaps.neigh_coef, snaps.neigh_eidx,
-                                         snaps.edge_feat @ p["w_edge"])
+                else kops.edge_aggregate(
+                    snaps.neigh_coef, snaps.neigh_eidx,
+                    kops.edge_project(snaps.edge_feat, p["w_edge"]))
                 for p in params["gcn"]]
 
     @staticmethod

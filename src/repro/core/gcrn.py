@@ -131,7 +131,8 @@ class GCRN:
 
         td = self.cfg.stream_td if td == "cfg" else td
         w_edge = params.get("w_edge")
-        edge_msg = snaps.edge_feat @ w_edge if w_edge is not None else None
+        edge_msg = (kops.edge_project(snaps.edge_feat, w_edge)
+                    if w_edge is not None else None)
         args = (snaps.neigh_idx, snaps.neigh_coef, snaps.neigh_eidx,
                 snaps.node_feat, snaps.renumber, snaps.node_mask,
                 state["h"], state["c"],
@@ -149,7 +150,8 @@ class GCRN:
                                                  state_residency=state_residency,
                                                  buffer_depth=buffer_depth,
                                                  force_ref=force_ref)
-        out = outs_h @ params["head"]["w"] + params["head"]["b"]
+        with jax.named_scope("head"):
+            out = outs_h @ params["head"]["w"] + params["head"]["b"]
         mask = snaps.node_mask
         if lengths is not None:
             # ragged T: the masking happens inside the launch; mirror it on

@@ -123,7 +123,7 @@ class StackedDGNN:
             )(snaps, x)
         p_last = params["gcn"][-1]
         w_edge = params["gcn"][0].get("w_edge")
-        edge_msg = (snaps.edge_feat @ w_edge
+        edge_msg = (kops.edge_project(snaps.edge_feat, w_edge)
                     if (w_edge is not None and len(params["gcn"]) == 1)
                     else None)
         args = (snaps.neigh_idx, snaps.neigh_coef, snaps.neigh_eidx,

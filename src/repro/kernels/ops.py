@@ -188,17 +188,28 @@ def _row_table(renumber, n_global: int):
     return jnp.where(renumber >= 0, renumber, n_global).astype(jnp.int32)
 
 
+def edge_project(edge_feat, w_edge):
+    """Per-edge messages ``edge_feat @ w_edge`` of the stream engine's
+    edge term, under the named scope ``edge_project`` (op metadata only:
+    a profile's device ops carry the name)."""
+    with jax.named_scope("edge_project"):
+        return edge_feat @ w_edge
+
+
 def edge_aggregate(coef, eidx, edge_msg):
     """Pre-aggregated ELL edge-message term ``sum_k coef[..., k] *
     edge_msg[eidx[..., k]]`` of shape (..., n, width): additive in the
     aggregation, so it factors out of the stream kernel, which then only
     aggregates node rows. ``edge_msg`` is (..., e, width) with the same
-    leading axes as ``eidx`` (..., n, k)."""
-    lead = eidx.shape[:-2]
-    n, k = eidx.shape[-2:]
-    g = jnp.take_along_axis(edge_msg, eidx.reshape(*lead, n * k, 1), axis=-2)
-    g = g.reshape(*lead, n, k, edge_msg.shape[-1])
-    return (g * coef[..., None]).sum(axis=-2)
+    leading axes as ``eidx`` (..., n, k). Runs under the named scope
+    ``edge_aggregate``, which names its gather in a profile."""
+    with jax.named_scope("edge_aggregate"):
+        lead = eidx.shape[:-2]
+        n, k = eidx.shape[-2:]
+        g = jnp.take_along_axis(edge_msg, eidx.reshape(*lead, n * k, 1),
+                                axis=-2)
+        g = g.reshape(*lead, n, k, edge_msg.shape[-1])
+        return (g * coef[..., None]).sum(axis=-2)
 
 
 def _gcrn_launch(batched, neigh_idx, neigh_coef, neigh_eidx, node_feat,

@@ -111,6 +111,7 @@ from repro.graph.padding import (
 )
 from repro.kernels import ops as kops
 from repro.serve.faults import LaunchTimeout, validate_snapshot
+from repro.serve.spans import RunTrace
 from repro.serve.supervision import SupervisionPolicy, TenantSupervisor
 
 # sid the single-tenant ``run`` path supervises its stream under (one
@@ -162,10 +163,36 @@ class ServeStats:
     # committed snapshot, in stream order]} — sojourn latency is this minus
     # the caller's arrival clock (benchmarks/kernel_bench does exactly that)
     commit_ms: dict = field(default_factory=dict)
+    # earlier stamps of the same snapshots, same clock and shape: when the
+    # producer took it from the tenant's iterator, when it was prepared and
+    # queued, and when the launch attempt that served it began
+    arrive_ms: dict = field(default_factory=dict)
+    ready_ms: dict = field(default_factory=dict)
+    launch_start_ms: dict = field(default_factory=dict)
+    # named serve-path spans (serve/spans.py): {name: summed wall ms} and
+    # {name: count}; per_snapshot_ms is serve.stack_batch + serve.dispatch
+    # + serve.device_wait of each launch, spread over its live snapshots
+    phase_ms: dict = field(default_factory=dict)
+    phase_n: dict = field(default_factory=dict)
+    # producer-thread CPU time of each preprocess_ms entry (the wall time
+    # less the waits for the GIL)
+    preprocess_cpu_ms: list = field(default_factory=list)
+
+    def _per_snapshot(self, *names: str) -> float:
+        n = len(self.per_snapshot_ms)  # one entry per served snapshot
+        return sum(self.phase_ms.get(k, 0.0) for k in names) / n if n else 0.0
 
     @property
-    def mean_latency_ms(self) -> float:
-        return float(np.mean(self.per_snapshot_ms)) if self.per_snapshot_ms else 0.0
+    def stage_ms_per_snapshot(self) -> float:
+        """Host staging of the launches (``serve.stage`` and
+        ``serve.stack_batch``) per served snapshot."""
+        return self._per_snapshot("serve.stage", "serve.stack_batch")
+
+    @property
+    def device_wait_ms_per_snapshot(self) -> float:
+        """Wait on the device's result (``serve.device_wait``) per served
+        snapshot."""
+        return self._per_snapshot("serve.device_wait")
 
     @property
     def tenant_errors(self) -> dict:
@@ -262,8 +289,7 @@ class SnapshotServer:
         self._fault_exempt = False   # calibration launches skip probes
         self._launch_ctx: tuple = ()  # live sids of the in-flight launch
         self._warmed: set = set()    # launch signatures past first compile
-        self._t0_run = 0.0           # run-start clock for commit stamps
-        self._commit_ms: dict = {}   # {sid: [commit ms since run start]}
+        self._trace = RunTrace()     # spans and stamps of the current run
         self._step = jax.jit(
             lambda p, s, snap: self.model.step(p, s, snap, mode=self.mode))
         # every v3 serve launch takes the batched ragged-T entry: chunk
@@ -374,22 +400,79 @@ class SnapshotServer:
 
     # ------------------------------------------------------ host thread ----
 
-    def _preprocess(self, snap: COOSnapshot,
-                    tenant=SOLO_SID) -> PaddedSnapshot:
-        # shapes must be static so the jitted step never recompiles (the
-        # "snapshot fits in BRAM" contract; overflow = the bucket chooser
-        # picked wrong and should raise). With ``buckets`` the shapes are
-        # static PER BUCKET: one compiled step per bucket in the jit cache.
+    def _prepare(self, snap: COOSnapshot, tenant=SOLO_SID, *, feat=None,
+                 pad: Optional[tuple] = None, defer: bool = False) -> tuple:
+        """Host prep of one snapshot: validate, renumber + normalize,
+        choose the bucket, pad. Returns ``(snapshot, (n, e, k) dims)``.
+
+        Shapes must be static so the jitted step never recompiles (the
+        "snapshot fits in BRAM" contract; overflow = the bucket chooser
+        picked wrong and should raise). With ``buckets`` the shapes are
+        static PER BUCKET: one compiled step per bucket in the jit cache.
+        ``pad`` fixes the bucket (the express lane's); ``defer`` leaves a
+        bucketed snapshot unpadded, for the device loop to pad once the
+        chunk's bucket — the max over its members — is known."""
+        feat = self.feat_table if feat is None else feat
         self._probe("preprocess", tenant=tenant)
-        validate_snapshot(snap, self.feat_table.shape[0], tenant=tenant)
+        validate_snapshot(snap, feat.shape[0], tenant=tenant)
         ls = renumber_and_normalize(snap)
-        if self.buckets is not None:
+        dims = (ls.n_nodes, ls.src.shape[0], max_in_degree(ls))
+        if pad is None and self.buckets is not None:
             self._probe("bucket", tenant=tenant)
-            n_pad, e_pad, k_max = choose_bucket(
-                ls.n_nodes, ls.src.shape[0], max_in_degree(ls), self.buckets)
-        else:
-            n_pad, e_pad, k_max = self.n_pad, self.e_pad, self.k_max
-        return pad_snapshot(ls, self.feat_table, n_pad, e_pad, k_max)
+            pad = choose_bucket(*dims, self.buckets)  # fails fast on no fit
+            if defer:
+                return ls, dims
+        elif pad is None:
+            # fixed bucket known up front: pad here so the host prep fully
+            # overlaps device work
+            pad = (self.n_pad, self.e_pad, self.k_max)
+        return pad_snapshot(ls, feat, *pad), dims
+
+    def _start_producer(self, sid, snaps: Iterable[COOSnapshot],
+                        q: queue.Queue, stop: threading.Event, prepare,
+                        name: str) -> threading.Thread:
+        """Start one host prep thread: ``prepare(snapshot, sid)`` each
+        snapshot of ``snaps`` under a ``serve.prep`` span, stamp its
+        arrival and readiness, and put the result on ``q`` in stream
+        order; then ``None`` at end-of-stream — or the ``BaseException``
+        the producer failed with (validation, no-fit bucket, injected
+        fault), which the device loop turns into a quarantine/raise per
+        policy. Puts wake on ``stop`` so shutdown never blocks on a full
+        queue."""
+        trace = self._trace
+        arrive = trace.arrive_ms.setdefault(sid, [])
+        ready = trace.ready_ms.setdefault(sid, [])
+
+        def put(item):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
+            return False
+
+        def producer():
+            try:
+                for s in snaps:
+                    t_arrive = trace.now_ms()
+                    c0 = time.thread_time()
+                    with trace.span("serve.prep", tenant=sid) as sp:
+                        item = prepare(s, sid)
+                    trace.prep_cpu_ms.append((time.thread_time() - c0) * 1e3)
+                    trace.prep_ms.append(sp.ms)
+                    arrive.append(t_arrive)
+                    ready.append(trace.now_ms())
+                    if not put(item):
+                        return
+                put(None)
+            except BaseException as exc:  # propagate, don't hang the consumer
+                put(exc)
+
+        th = threading.Thread(target=producer, daemon=True, name=name)
+        with trace.span("serve.spawn", tenant=sid):
+            th.start()  # waits for the new thread to run, GIL included
+        return th
 
     # -------------------------------------------------------- shutdown ----
 
@@ -409,15 +492,16 @@ class SnapshotServer:
         on the stop-aware put wakes on the event). A thread still alive
         past the deadline is stuck in user iterator code — warned about,
         since Python offers no way to kill it."""
-        stop.set()
-        deadline = time.perf_counter() + _SHUTDOWN_DEADLINE_S
-        alive = [th for th in threads if th.is_alive()]
-        while alive and time.perf_counter() < deadline:
-            for q in queues:
-                self._drain(q)
-            for th in alive:
-                th.join(timeout=0.05)
-            alive = [th for th in alive if th.is_alive()]
+        with self._trace.span("serve.shutdown"):
+            stop.set()
+            deadline = time.perf_counter() + _SHUTDOWN_DEADLINE_S
+            alive = [th for th in threads if th.is_alive()]
+            while alive and time.perf_counter() < deadline:
+                for q in queues:
+                    self._drain(q)
+                for th in alive:
+                    th.join(timeout=0.05)
+                alive = [th for th in alive if th.is_alive()]
         for th in alive:
             warnings.warn(f"serve producer thread {th.name!r} did not exit "
                           "within the shutdown deadline (stuck in the "
@@ -441,14 +525,14 @@ class SnapshotServer:
                 f"{sorted(REGISTRY)}")
         return True
 
-    def _launch_ragged(self, params, states_B, per_stream: list,
+    def _launch_ragged(self, params, states_B, batch_BT,
                        lengths: np.ndarray, force_ref: bool = False):
-        """ONE batched ragged-T stream launch: ``per_stream`` are (T, ...)
-        stacked chunks of equal padded shape, ``lengths`` their true live
-        lengths (0 = pure batch-padding row). The dead slots are masked
-        in-launch by the plan's ragged capability. ``force_ref`` routes to
-        the jitted oracle twin (degraded-mode rung)."""
-        batch_BT = stack_streams(per_stream)
+        """ONE batched ragged-T stream launch: ``batch_BT`` is the
+        (B, T, ...) ``stack_streams`` of equal-shape chunks, ``lengths``
+        their true live lengths (0 = pure batch-padding row). The dead
+        slots are masked in-launch by the plan's ragged capability.
+        ``force_ref`` routes to the jitted oracle twin (degraded-mode
+        rung)."""
         fn = (self._stream_step_batched_ref if force_ref
               else self._stream_step_batched)
         return fn(params, states_B, batch_BT,
@@ -456,13 +540,16 @@ class SnapshotServer:
 
     # -------------------------------------------------- supervised launch ----
 
-    def _count_launch(self, ctr: dict, family: str) -> None:
+    def _count_launch(self, ctr: dict, family: str) -> int:
+        """Count one launch attempt; returns its index in the run (the
+        ``launch`` argument of its spans)."""
         ctr["launches"] += 1
         bf = ctr.setdefault("by_family", {})
         bf[family] = bf.get(family, 0) + 1
+        return ctr["launches"]
 
     def _stage_group(self, params, states: dict, group: list,
-                     force_ref: bool = False) -> tuple:
+                     force_ref: bool = False, launch: int = 0) -> tuple:
         """Launch one batched V3 group WITHOUT committing anything: build
         the (B, T) batch, run it, and return the staged per-tenant results
         ``(staged_states, staged_outs, dt_per_snapshot_ms, live, padded)``.
@@ -485,41 +572,52 @@ class SnapshotServer:
         raises ``LaunchTimeout`` and is DISCARDED by the caller. The first
         launch of each (bucket, T, B, path) signature is exempt — it pays
         one-time compilation.
+
+        The phases run under the spans ``serve.stage`` (everything before
+        the timed launch), ``serve.stack_batch``, ``serve.dispatch`` and
+        ``serve.device_wait`` (the timed launch wall) and
+        ``serve.unstage``, each with the ``launch`` index.
         """
+        span = self._trace.span
         bucket = group[0][2]
         real_lens = [len(chunk) for _, chunk, _ in group]
         target = pow2_target(max(real_lens), cap=self.stream_chunk)
         b_real = len(group)
         b_target = pow2_target(b_real)
-        per_stream = []
-        for _, chunk, _ in group:
-            # fixed-bucket items arrive pre-padded from the producer thread
-            # (host-prep overlap); bucketed items pad here, once the chunk
-            # bucket is known.
-            padded = [ls if isinstance(ls, PaddedSnapshot)
-                      else pad_snapshot(ls, self.feat_table, *bucket)
-                      for ls in chunk]
-            # ragged T: tail slots repeat the last snapshot — dead
-            # ``lengths`` slots, masked in-launch, content irrelevant
-            padded = padded + [padded[-1]] * (target - len(padded))
-            per_stream.append(stack_time(padded))
-        # batch-axis padding = length-0 streams (results discarded)
-        per_stream.extend([per_stream[0]] * (b_target - b_real))
-        lengths = np.asarray(real_lens + [0] * (b_target - b_real), np.int32)
-        zero_state = jax.tree.map(jnp.zeros_like, states[group[0][0]])
-        states_B = jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=0),
-            *([states[sid] for sid, _, _ in group]
-              + [zero_state] * (b_target - b_real)))
+        with span("serve.stage", launch=launch, B=b_target, T=target):
+            per_stream = []
+            for _, chunk, _ in group:
+                # fixed-bucket items arrive pre-padded from the producer
+                # thread (host-prep overlap); bucketed items pad here, once
+                # the chunk bucket is known.
+                padded = [ls if isinstance(ls, PaddedSnapshot)
+                          else pad_snapshot(ls, self.feat_table, *bucket)
+                          for ls in chunk]
+                # ragged T: tail slots repeat the last snapshot — dead
+                # ``lengths`` slots, masked in-launch, content irrelevant
+                padded = padded + [padded[-1]] * (target - len(padded))
+                per_stream.append(stack_time(padded))
+            # batch-axis padding = length-0 streams (results discarded)
+            per_stream.extend([per_stream[0]] * (b_target - b_real))
+            lengths = np.asarray(real_lens + [0] * (b_target - b_real),
+                                 np.int32)
+            zero_state = jax.tree.map(jnp.zeros_like, states[group[0][0]])
+            states_B = jax.tree.map(
+                lambda *xs: jnp.stack(xs, axis=0),
+                *([states[sid] for sid, _, _ in group]
+                  + [zero_state] * (b_target - b_real)))
         key = (bucket, target, b_target, force_ref)
         warmed = key in self._warmed
         self._launch_ctx = tuple(sid for sid, _, _ in group)
         try:
             t0 = time.perf_counter()
-            states_B, out_BT = self._launch_ragged(params, states_B,
-                                                   per_stream, lengths,
-                                                   force_ref=force_ref)
-            jax.block_until_ready(out_BT)
+            with span("serve.stack_batch", t0, launch=launch) as sp:
+                batch_BT = stack_streams(per_stream)
+            with span("serve.dispatch", sp.t1, launch=launch) as sp:
+                states_B, out_BT = self._launch_ragged(
+                    params, states_B, batch_BT, lengths, force_ref=force_ref)
+            with span("serve.device_wait", sp.t1, launch=launch):
+                jax.block_until_ready(out_BT)
             dt_ms = (time.perf_counter() - t0) * 1e3
         finally:
             self._launch_ctx = ()
@@ -530,24 +628,26 @@ class SnapshotServer:
                 f"launch took {dt_ms:.1f}ms > launch_timeout_ms={timeout}"
                 f" (bucket={bucket}, B={b_target}, T={target}); result "
                 "discarded", site="launch")
-        out_np = np.asarray(out_BT)
-        staged_states = {
-            sid: jax.tree.map(lambda a, b=b: a[b], states_B)
-            for b, (sid, _, _) in enumerate(group)}
-        staged_outs = {sid: [out_np[b, t] for t in range(real_lens[b])]
-                       for b, (sid, _, _) in enumerate(group)}
+        with span("serve.unstage", launch=launch):
+            out_np = np.asarray(out_BT)
+            staged_states = {
+                sid: jax.tree.map(lambda a, b=b: a[b], states_B)
+                for b, (sid, _, _) in enumerate(group)}
+            staged_outs = {sid: [out_np[b, t] for t in range(real_lens[b])]
+                           for b, (sid, _, _) in enumerate(group)}
         live = sum(real_lens)
         padded_slots = b_target * target - live
         return staged_states, staged_outs, dt_ms / live, live, padded_slots
 
     def _commit_group(self, states: dict, group: list, staged: tuple,
                       outs: dict, lat: list, ctr: dict, sup: TenantSupervisor,
-                      degraded: bool = False) -> None:
+                      launch_ms: float, degraded: bool = False) -> None:
         """Commit one staged group: the ``evolve`` fault site sits inside
         the state-commit loop, so an injected (or real) mid-commit failure
         leaves ``states`` partially written — exactly what the
         supervisor's checkpoint/rollback must undo for the replay to
-        evolve state exactly once per served snapshot."""
+        evolve state exactly once per served snapshot. ``launch_ms`` is
+        when the launch attempt began (ms since run start)."""
         staged_states, staged_outs, dt, live, padded_slots = staged
         for sid, _, _ in group:
             self._probe("evolve", tenant=sid)
@@ -555,10 +655,13 @@ class SnapshotServer:
         # commit wall-clock (ms since run start) recorded per snapshot —
         # only after the whole evolve loop, so a rolled-back commit never
         # stamps timestamps for outputs it did not serve
-        now_ms = (time.perf_counter() - self._t0_run) * 1e3
+        trace = self._trace
+        now_ms = trace.now_ms()
         for sid, chunk, _ in group:
             outs[sid].extend(staged_outs[sid])
-            self._commit_ms.setdefault(sid, []).extend([now_ms] * len(chunk))
+            trace.commit_ms.setdefault(sid, []).extend([now_ms] * len(chunk))
+            trace.launch_start_ms.setdefault(sid, []).extend(
+                [launch_ms] * len(chunk))
             lat.extend([dt] * len(chunk))
             if degraded:
                 sup.note_degraded(sid)
@@ -566,6 +669,42 @@ class SnapshotServer:
         ctr["padded"] += padded_slots
         if degraded:
             ctr["degraded"] += 1
+
+    def _attempt(self, params, states: dict, members: list, outs: dict,
+                 lat: list, ctr: dict, sup: TenantSupervisor,
+                 force_ref: bool = False, degraded: bool = False):
+        """One launch attempt of ``members`` under a ``serve.launch`` span:
+        checkpoint their state, stage the launch, commit it; on a failure
+        roll the state back. Returns None when the attempt served, else the
+        attributed error. The static temporal contract has NOTHING to
+        checkpoint — tenant state is empty and never advances — so the
+        express-lane promise (no checkpoint/rollback overhead around
+        stateless launches) holds for a static-family session on the
+        regular path too."""
+        span = self._trace.span
+        sids = [sid for sid, _, _ in members]
+        k = self._count_launch(ctr, self.model.stream_family)
+        with span("serve.launch", launch=k, B=len(members)) as attempt:
+            ckpt = None
+            if self.plan.temporal != "static":
+                with span("serve.checkpoint", launch=k):
+                    ckpt = sup.checkpoint(states, sids)
+            try:
+                staged = self._stage_group(params, states, members,
+                                           force_ref=force_ref, launch=k)
+                with span("serve.commit", launch=k):
+                    self._commit_group(states, members, staged, outs, lat,
+                                       ctr, sup, launch_ms=attempt.start_ms,
+                                       degraded=degraded)
+                return None
+            except Exception as exc:
+                err = self._attribution(exc)
+                if isinstance(err, LaunchTimeout):
+                    ctr["timeouts"] += 1
+                if ckpt is not None:
+                    with span("serve.checkpoint", launch=k):
+                        sup.rollback(states, ckpt)
+                return err
 
     def _degrade_group(self, params, states: dict, members: list,
                        outs: dict, lat: list, ctr: dict,
@@ -576,27 +715,17 @@ class SnapshotServer:
         gate) serves correct-but-slower results. A member that fails every
         rung is quarantined (isolate) or raises (strict) with the LAST
         error as cause."""
-        static = self.plan.temporal == "static"
         for member in members:
-            sid = member[0]
             err = cause
             for force_ref in (False, True):
-                ckpt = None if static else sup.checkpoint(states, [sid])
-                try:
-                    self._count_launch(ctr, self.model.stream_family)
-                    staged = self._stage_group(params, states, [member],
-                                               force_ref=force_ref)
-                    self._commit_group(states, [member], staged, outs, lat,
-                                       ctr, sup, degraded=True)
+                failed = self._attempt(params, states, [member], outs, lat,
+                                       ctr, sup, force_ref=force_ref,
+                                       degraded=True)
+                if failed is None:
                     break
-                except Exception as exc:
-                    err = self._attribution(exc)
-                    if isinstance(err, LaunchTimeout):
-                        ctr["timeouts"] += 1
-                    if ckpt is not None:
-                        sup.rollback(states, ckpt)
+                err = failed
             else:
-                sup.quarantine(sid, err,
+                sup.quarantine(member[0], err,
                                site=getattr(err, "site", "launch"))
 
     def _run_group_supervised(self, params, states: dict, group: list,
@@ -618,48 +747,33 @@ class SnapshotServer:
         """
         members = [m for m in group if sup.ok(m[0])]
         attempt = 0
-        # the static temporal contract has NOTHING to checkpoint — tenant
-        # state is empty and never advances — so the express-lane promise
-        # (no checkpoint/rollback overhead around stateless launches)
-        # holds for a static-family session on the regular path too.
-        static = self.plan.temporal == "static"
         while members:
             sids = [sid for sid, _, _ in members]
-            ckpt = None if static else sup.checkpoint(states, sids)
-            try:
-                self._count_launch(ctr, self.model.stream_family)
-                staged = self._stage_group(params, states, members)
-                self._commit_group(states, members, staged, outs, lat, ctr,
-                                   sup)
+            err = self._attempt(params, states, members, outs, lat, ctr, sup)
+            if err is None:
                 return
-            except Exception as exc:
-                err = self._attribution(exc)
-                if isinstance(err, LaunchTimeout):
-                    ctr["timeouts"] += 1
-                if ckpt is not None:
-                    sup.rollback(states, ckpt)
-                attempt += 1
-                if attempt <= self._policy.max_retries:
-                    sup.note_retry(sids, attempt)
-                    continue
-                tenant = getattr(err, "tenant", None)
-                if tenant is not None and tenant in sids:
-                    # persistent fault pinned to one member: quarantine it,
-                    # retry the healthy co-batch without it
-                    sup.quarantine(tenant, err,
-                                   site=getattr(err, "site", "launch"))
-                    members = [m for m in members if m[0] != tenant]
-                    attempt = 0
-                    continue
-                if self._policy.degrade:
-                    self._degrade_group(params, states, members, outs, lat,
-                                        ctr, sup, err)
-                    return
-                # no ladder: the whole group fails together
-                for sid in sids:
-                    sup.quarantine(sid, err,
-                                   site=getattr(err, "site", "launch"))
+            attempt += 1
+            if attempt <= self._policy.max_retries:
+                sup.note_retry(sids, attempt)
+                continue
+            tenant = getattr(err, "tenant", None)
+            if tenant is not None and tenant in sids:
+                # persistent fault pinned to one member: quarantine it,
+                # retry the healthy co-batch without it
+                sup.quarantine(tenant, err,
+                               site=getattr(err, "site", "launch"))
+                members = [m for m in members if m[0] != tenant]
+                attempt = 0
+                continue
+            if self._policy.degrade:
+                self._degrade_group(params, states, members, outs, lat,
+                                    ctr, sup, err)
                 return
+            # no ladder: the whole group fails together
+            for sid in sids:
+                sup.quarantine(sid, err,
+                               site=getattr(err, "site", "launch"))
+            return
 
     def _run_chunk(self, params, states: dict, chunk: list, outs: dict,
                    lat: list, ctr: dict, sup: TenantSupervisor) -> None:
@@ -678,11 +792,28 @@ class SnapshotServer:
                                    [(SOLO_SID, chunk, bucket)], outs, lat,
                                    ctr, sup)
 
-    def _make_stats(self, lat, pre_ms, total, ctr,
+    def _step_one(self, params, state, ps: PaddedSnapshot,
+                  lat: list) -> tuple:
+        """One per-snapshot step of the non-v3 engine modes: dispatch,
+        wait, output to host. Returns ``(new_state, output)``."""
+        span = self._trace.span
+        t0 = time.perf_counter()
+        with span("serve.dispatch"):
+            state, out = self._step(params, state, ps)
+        with span("serve.device_wait"):
+            jax.block_until_ready(out)
+        lat.append((time.perf_counter() - t0) * 1e3)
+        with span("serve.unstage"):
+            return state, np.asarray(out)
+
+    def _make_stats(self, lat, ctr,
                     sup: Optional[TenantSupervisor]) -> ServeStats:
+        """The run's ``ServeStats``; its total wall ends now."""
+        trace = self._trace
+        total = trace.now_ms()
         totals = sup.totals() if sup is not None else {}
         return ServeStats(
-            lat, pre_ms, total,
+            lat, list(trace.prep_ms), total,
             live_snapshots=ctr["live"], padded_snapshots=ctr["padded"],
             promoted_chunks=ctr["promoted"], launches=ctr["launches"],
             express_launches=ctr.get("express", 0),
@@ -697,7 +828,11 @@ class SnapshotServer:
             prefill_chunks=ctr.get("prefill", 0),
             evictions=totals.get("evictions", 0),
             recoveries=totals.get("recoveries", 0),
-            commit_ms=dict(self._commit_ms))
+            commit_ms=dict(trace.commit_ms),
+            **trace.stamps(),
+            phase_ms=dict(trace.phase_ms),
+            phase_n=dict(trace.phase_n),
+            preprocess_cpu_ms=list(trace.prep_cpu_ms))
 
     def run(self, params, state, snaps: Iterable[COOSnapshot]) -> tuple:
         """Returns (final_state, outputs list, ServeStats).
@@ -717,35 +852,12 @@ class SnapshotServer:
         depth = (max(self.queue_depth, self.stream_chunk)
                  if self._use_stream() else self.queue_depth)
         q: queue.Queue = queue.Queue(maxsize=depth)
-        pre_ms: list = []
         stop = threading.Event()
-
-        def _put(item):
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def producer():
-            try:
-                for s in snaps:
-                    t0 = time.perf_counter()
-                    ps = self._preprocess(s)
-                    pre_ms.append((time.perf_counter() - t0) * 1e3)
-                    if not _put(ps):
-                        return
-                _put(None)
-            except BaseException as exc:  # propagate, don't hang the consumer
-                _put(exc)
-
-        th = threading.Thread(target=producer, daemon=True,
-                              name=f"dgnn-serve-producer-{SOLO_SID}")
-        t_start = time.perf_counter()
-        self._t0_run, self._commit_ms = t_start, {}
-        th.start()
+        self._trace = RunTrace()
+        span = self._trace.span
+        th = self._start_producer(SOLO_SID, snaps, q, stop,
+                                  lambda s, sid: self._prepare(s, sid)[0],
+                                  name=f"dgnn-serve-producer-{SOLO_SID}")
         outs: list = []
         lat: list = []
         ctr = {"live": 0, "padded": 0, "promoted": 0, "launches": 0,
@@ -759,7 +871,8 @@ class SnapshotServer:
         try:
             with self._fault_window():
                 while sup.ok(SOLO_SID):
-                    ps = q.get()
+                    with span("serve.wait_producers"):
+                        ps = q.get()
                     if ps is None:
                         break
                     if isinstance(ps, BaseException):
@@ -771,12 +884,9 @@ class SnapshotServer:
                     if not use_stream:
                         ckpt = sup.checkpoint(states, [SOLO_SID])
                         try:
-                            t0 = time.perf_counter()
-                            states[SOLO_SID], out = self._step(
-                                params, states[SOLO_SID], ps)
-                            jax.block_until_ready(out)
-                            lat.append((time.perf_counter() - t0) * 1e3)
-                            outs.append(np.asarray(out))
+                            states[SOLO_SID], out = self._step_one(
+                                params, states[SOLO_SID], ps, lat)
+                            outs.append(out)
                         except Exception as exc:
                             sup.rollback(states, ckpt)
                             sup.quarantine(SOLO_SID, self._attribution(exc))
@@ -798,9 +908,7 @@ class SnapshotServer:
                                     sup)
         finally:
             self._shutdown(stop, [q], [th])
-        total = (time.perf_counter() - t_start) * 1e3
-        return states[SOLO_SID], outs, self._make_stats(lat, pre_ms, total,
-                                                        ctr, sup)
+        return states[SOLO_SID], outs, self._make_stats(lat, ctr, sup)
 
     # ------------------------------------------- multi-tenant device loop ----
 
@@ -831,9 +939,9 @@ class SnapshotServer:
 
         Calibration launches are WARM-UP, not serving: they go straight
         through ``_launch_ragged`` (never ``_stage_group``), so they touch
-        neither ``ServeStats.launches`` nor ``per_snapshot_ms``, and the
-        ``_fault_exempt`` window keeps them out of launch-site occurrence
-        counting — stats and fault windows on a run are identical with
+        neither ``ServeStats.launches``, ``per_snapshot_ms`` nor the spans'
+        ``phase_ms``, and the ``_fault_exempt`` window keeps them out of
+        launch-site occurrence counting — stats and fault windows on a run are identical with
         ``promotion_guard`` "measured" or "static" (pinned by the
         calibration-isolation regression test)."""
         din = self.feat_table.shape[1]
@@ -846,8 +954,9 @@ class SnapshotServer:
                 chunk = [empty_padded(*bucket, din, de)] * T
                 state = self.model.init_state(params, mode=self.mode)
                 state_B = jax.tree.map(lambda a: a[None], state)
-                run = lambda: self._launch_ragged(
-                    params, state_B, [stack_time(chunk)], np.asarray([T]))
+                batch = stack_streams([stack_time(chunk)])
+                run = lambda: self._launch_ragged(params, state_B, batch,
+                                                  np.asarray([T]))
                 jax.block_until_ready(run())  # compile + warm
                 t0 = time.perf_counter()
                 jax.block_until_ready(run())
@@ -895,107 +1004,42 @@ class SnapshotServer:
 
     def _spawn_producers(self, streams: dict) -> tuple:
         """Start one host preprocessing thread per tenant stream (shared
-        by the round-based and continuous device loops). Returns
-        ``(queues, pre_ms, stop_event, threads)`` with the threads already
-        running. Each queue carries ``(LocalSnapshot | PaddedSnapshot,
-        dims)`` items in stream order, then ``None`` at end-of-stream — or
-        a ``BaseException`` if the producer failed (validation, no-fit
-        bucket, injected fault), which the device loop turns into a
-        quarantine/raise per policy."""
+        by the round-based and continuous device loops; see
+        ``_start_producer``). Returns ``(queues, stop_event, threads)``
+        with the threads already running. Each queue carries
+        ``(LocalSnapshot | PaddedSnapshot, dims)`` items in stream order:
+        a bucketed snapshot is padded on the device loop, once the chunk
+        bucket is known."""
         sids = sorted(streams)
         qs = {sid: queue.Queue(maxsize=max(self.queue_depth,
                                            self.stream_chunk))
               for sid in sids}
-        pre_ms: list = []
         stop = threading.Event()
-
-        def _put(q, item):
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def producer(sid):
-            try:
-                for s in streams[sid]:
-                    t0 = time.perf_counter()
-                    self._probe("preprocess", tenant=sid)
-                    validate_snapshot(s, self.feat_table.shape[0],
-                                      tenant=sid)
-                    ls = renumber_and_normalize(s)
-                    dims = (ls.n_nodes, ls.src.shape[0], max_in_degree(ls))
-                    if self.buckets is not None:
-                        self._probe("bucket", tenant=sid)
-                        choose_bucket(*dims, self.buckets)  # fail fast
-                    else:
-                        # fixed bucket known up front: pad here so the host
-                        # prep fully overlaps device work (the bucketed
-                        # case defers padding until the chunk bucket — max
-                        # over its members — is known on the device loop).
-                        ls = pad_snapshot(ls, self.feat_table, self.n_pad,
-                                          self.e_pad, self.k_max)
-                    pre_ms.append((time.perf_counter() - t0) * 1e3)
-                    if not _put(qs[sid], (ls, dims)):
-                        return
-                _put(qs[sid], None)
-            except BaseException as exc:  # propagate, don't hang the consumer
-                _put(qs[sid], exc)
-
-        threads = [threading.Thread(target=producer, args=(sid,), daemon=True,
-                                    name=f"dgnn-serve-producer-{sid}")
-                   for sid in sids]
-        for th in threads:
-            th.start()
-        return qs, pre_ms, stop, threads
+        threads = [self._start_producer(
+            sid, streams[sid], qs[sid], stop,
+            lambda s, sid: self._prepare(s, sid, defer=True),
+            name=f"dgnn-serve-producer-{sid}") for sid in sids]
+        return qs, stop, threads
 
     # ---------------------------------------------------- express lane ----
 
-    def _spawn_express_producers(self, streams: dict, stop, pre_ms) -> tuple:
+    def _spawn_express_producers(self, streams: dict, stop) -> tuple:
         """Producer threads for the stateless express tenants. Always
         fixed-bucket (the lane co-batches every slot into one shape, so
-        padding happens host-side, fully overlapped). Items mirror the
+        padding happens host-side, fully overlapped). Items have the
         recurrent producers' ``(payload, dims)`` shape so both feed the
         same admission code; ``stop`` is the shared shutdown event."""
         xp = self.express.plan
+        pad = (xp.n_pad, xp.e_pad, xp.k_max)
         sids = sorted(streams)
         qs = {sid: queue.Queue(maxsize=max(self.queue_depth,
                                            self.stream_chunk))
               for sid in sids}
-
-        def _put(q, item):
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    return True
-                except queue.Full:
-                    continue
-            return False
-
-        def producer(sid):
-            try:
-                for s in streams[sid]:
-                    t0 = time.perf_counter()
-                    self._probe("preprocess", tenant=sid)
-                    validate_snapshot(s, self._express_feat.shape[0],
-                                      tenant=sid)
-                    ls = renumber_and_normalize(s)
-                    ps = pad_snapshot(ls, self._express_feat, xp.n_pad,
-                                      xp.e_pad, xp.k_max)
-                    pre_ms.append((time.perf_counter() - t0) * 1e3)
-                    if not _put(qs[sid], (ps, None)):
-                        return
-                _put(qs[sid], None)
-            except BaseException as exc:  # propagate, don't hang the consumer
-                _put(qs[sid], exc)
-
-        threads = [threading.Thread(target=producer, args=(sid,), daemon=True,
-                                    name=f"dgnn-serve-express-{sid}")
-                   for sid in sids]
-        for th in threads:
-            th.start()
+        threads = [self._start_producer(
+            sid, streams[sid], qs[sid], stop,
+            lambda s, sid: self._prepare(s, sid, feat=self._express_feat,
+                                         pad=pad),
+            name=f"dgnn-serve-express-{sid}") for sid in sids]
         return qs, threads
 
     def _run_express_group(self, params_x, group: list, outs: dict,
@@ -1011,42 +1055,47 @@ class SnapshotServer:
         ladder (there is no cheaper rung below a stateless launch)."""
         members = [m for m in group if sup.ok(m[0])]
         attempt = 0
+        trace = self._trace
         while members:
             slots = [(sid, ps) for sid, chunk in members for ps in chunk]
             sids = sorted({sid for sid, _ in slots})
             b_real = len(slots)
             b_target = pow2_target(b_real)
-            per_slot = [stack_time([ps]) for _, ps in slots]
-            per_slot.extend([per_slot[0]] * (b_target - b_real))
-            lengths = np.asarray([1] * b_real + [0] * (b_target - b_real),
-                                 np.int32)
             key = ("express", b_target)
             warmed = key in self._warmed
             self._launch_ctx = tuple(sids)
+            k = self._count_launch(ctr, self.express.model.stream_family)
+            ctr["express"] = ctr.get("express", 0) + 1
             try:
-                self._count_launch(ctr, self.express.model.stream_family)
-                ctr["express"] = ctr.get("express", 0) + 1
-                t0 = time.perf_counter()
-                out_BT = self._express_step(params_x,
-                                            stack_streams(per_slot),
-                                            jnp.asarray(lengths, jnp.int32))
-                jax.block_until_ready(out_BT)
-                dt_ms = (time.perf_counter() - t0) * 1e3
-                self._warmed.add(key)
-                timeout = self._policy.timeout_ms
-                if timeout is not None and warmed and dt_ms > timeout:
-                    raise LaunchTimeout(
-                        f"express launch took {dt_ms:.1f}ms > "
-                        f"launch_timeout_ms={timeout} (B={b_target}); "
-                        "result discarded", site="launch")
-                out_np = np.asarray(out_BT)
-                now_ms = (time.perf_counter() - self._t0_run) * 1e3
-                for b, (sid, _) in enumerate(slots):
-                    outs[sid].append(out_np[b, 0])
-                    self._commit_ms.setdefault(sid, []).append(now_ms)
-                lat.extend([dt_ms / b_real] * b_real)
-                ctr["live"] += b_real
-                ctr["padded"] += b_target - b_real
+                with trace.span("serve.express", launch=k,
+                                B=b_target) as launch:
+                    per_slot = [stack_time([ps]) for _, ps in slots]
+                    per_slot.extend([per_slot[0]] * (b_target - b_real))
+                    lengths = np.asarray(
+                        [1] * b_real + [0] * (b_target - b_real), np.int32)
+                    t0 = time.perf_counter()
+                    out_BT = self._express_step(
+                        params_x, stack_streams(per_slot),
+                        jnp.asarray(lengths, jnp.int32))
+                    jax.block_until_ready(out_BT)
+                    dt_ms = (time.perf_counter() - t0) * 1e3
+                    self._warmed.add(key)
+                    timeout = self._policy.timeout_ms
+                    if timeout is not None and warmed and dt_ms > timeout:
+                        raise LaunchTimeout(
+                            f"express launch took {dt_ms:.1f}ms > "
+                            f"launch_timeout_ms={timeout} (B={b_target}); "
+                            "result discarded", site="launch")
+                    out_np = np.asarray(out_BT)
+                    now_ms = trace.now_ms()
+                    for b, (sid, _) in enumerate(slots):
+                        outs[sid].append(out_np[b, 0])
+                        trace.commit_ms.setdefault(sid, []).append(now_ms)
+                        trace.launch_start_ms.setdefault(sid, []).append(
+                            launch.start_ms)
+                    lat.extend([dt_ms / b_real] * b_real)
+                    ctr["live"] += b_real
+                    ctr["padded"] += b_target - b_real
                 return
             except Exception as exc:
                 err = self._attribution(exc)
@@ -1069,6 +1118,63 @@ class SnapshotServer:
                 return
             finally:
                 self._launch_ctx = ()
+
+    def _pull_express(self, xqs: dict, x_active: set,
+                      sup: TenantSupervisor) -> list:
+        """The express round's pull: every active express tenant's next
+        chunk of up to ``stream_chunk`` T=1 slots, as ``[(sid, chunk)]``.
+        End-of-stream retires a tenant from ``x_active``; a producer
+        failure also quarantines it per policy."""
+        x_group = []
+        for sid in sorted(x_active):
+            chunk = []
+            while len(chunk) < self.stream_chunk:
+                item = xqs[sid].get()
+                if item is None:
+                    x_active.discard(sid)
+                    break
+                if isinstance(item, BaseException):
+                    x_active.discard(sid)
+                    chunk = []
+                    sup.quarantine(sid, item,
+                                   site=getattr(item, "site", None))
+                    break
+                chunk.append(item[0])
+            if chunk:
+                x_group.append((sid, chunk))
+        return x_group
+
+    def _pull_round(self, qs: dict, active: set, sup: TenantSupervisor,
+                    batched: bool) -> dict:
+        """The round loop's pull: the next chunk of up to ``stream_chunk``
+        snapshots (one for the non-v3 loop) of every active stream, as
+        ``{sid: (chunk, dims)}``. End-of-stream retires a tenant from
+        ``active``. A producer-side failure (validation, no-fit bucket,
+        injected fault) raises under strict; isolate quarantines THIS
+        tenant — outputs stop at the last committed chunk, the round
+        continues without it."""
+        chunks = {}
+        for sid in sorted(active):
+            chunk: list = []
+            dims: list = []
+            while len(chunk) < self.stream_chunk:
+                item = qs[sid].get()
+                if item is None:
+                    active.discard(sid)
+                    break
+                if isinstance(item, BaseException):
+                    active.discard(sid)
+                    chunk = []
+                    sup.quarantine(sid, item,
+                                   site=getattr(item, "site", None))
+                    break
+                chunk.append(item[0])
+                dims.append(item[1])
+                if not batched:
+                    break  # non-v3 loop: no chunking
+            if chunk:
+                chunks[sid] = (chunk, dims)
+        return chunks
 
     def _check_express_args(self, streams: dict, express_streams) -> list:
         """Validate the run_multi express arguments; returns the express
@@ -1145,13 +1251,13 @@ class SnapshotServer:
         "rounds"); see ``run_multi`` for the contract."""
         sids = sorted(streams)
         x_sids = sorted(express_streams or {})
-        t_start = time.perf_counter()
-        self._t0_run, self._commit_ms = t_start, {}
-        qs, pre_ms, stop, threads = self._spawn_producers(streams)
+        self._trace = RunTrace()
+        span = self._trace.span
+        qs, stop, threads = self._spawn_producers(streams)
         xqs: dict = {}
         if x_sids:
             xqs, x_threads = self._spawn_express_producers(
-                express_streams, stop, pre_ms)
+                express_streams, stop)
             threads = threads + x_threads
         outs: dict = {sid: [] for sid in sids + x_sids}
         lat: list = []
@@ -1167,23 +1273,9 @@ class SnapshotServer:
                     # express round: every express tenant's next chunk of
                     # T=1 slots, co-batched into ONE stateless launch
                     x_group: list = []
-                    for sid in sorted(x_active):
-                        chunk = []
-                        while len(chunk) < self.stream_chunk:
-                            item = xqs[sid].get()
-                            if item is None:
-                                x_active.discard(sid)
-                                break
-                            if isinstance(item, BaseException):
-                                x_active.discard(sid)
-                                chunk = []
-                                sup.quarantine(sid, item,
-                                               site=getattr(item, "site",
-                                                            None))
-                                break
-                            chunk.append(item[0])
-                        if chunk:
-                            x_group.append((sid, chunk))
+                    if x_active:
+                        with span("serve.wait_producers", express=True):
+                            x_group = self._pull_express(xqs, x_active, sup)
                     if x_group:
                         self._run_express_group(express_params, x_group,
                                                 outs, lat, ctr, sup)
@@ -1191,33 +1283,8 @@ class SnapshotServer:
                     if not active:
                         continue
                     # one round: pull the next chunk of every active stream
-                    chunks = {}
-                    for sid in sorted(active):
-                        chunk: list = []
-                        dims: list = []
-                        while len(chunk) < self.stream_chunk:
-                            item = qs[sid].get()
-                            if item is None:
-                                active.discard(sid)
-                                break
-                            if isinstance(item, BaseException):
-                                # producer-side failure (validation,
-                                # no-fit bucket, injected fault): strict
-                                # raises; isolate quarantines THIS tenant
-                                # — outputs stop at the last committed
-                                # chunk, the round continues without it
-                                active.discard(sid)
-                                chunk = []
-                                sup.quarantine(sid, item,
-                                               site=getattr(item, "site",
-                                                            None))
-                                break
-                            chunk.append(item[0])
-                            dims.append(item[1])
-                            if not batched and chunk:
-                                break  # non-v3 loop: no chunking
-                        if chunk:
-                            chunks[sid] = (chunk, dims)
+                    with span("serve.wait_producers"):
+                        chunks = self._pull_round(qs, active, sup, batched)
                     if not chunks:
                         continue
                     if not batched:
@@ -1234,13 +1301,9 @@ class SnapshotServer:
                                               ls, self.feat_table,
                                               *self._chunk_bucket([d])))
                                     ckpt = sup.checkpoint(states, [sid])
-                                    t0 = time.perf_counter()
-                                    states[sid], out = self._step(
-                                        params, states[sid], ps)
-                                    jax.block_until_ready(out)
-                                    lat.append(
-                                        (time.perf_counter() - t0) * 1e3)
-                                    outs[sid].append(np.asarray(out))
+                                    states[sid], out = self._step_one(
+                                        params, states[sid], ps, lat)
+                                    outs[sid].append(out)
                             except Exception as exc:
                                 sup.rollback(states, ckpt)
                                 sup.quarantine(sid, self._attribution(exc))
@@ -1278,5 +1341,4 @@ class SnapshotServer:
         finally:
             self._shutdown(stop, list(qs.values()) + list(xqs.values()),
                            threads)
-        total = (time.perf_counter() - t_start) * 1e3
-        return states, outs, self._make_stats(lat, pre_ms, total, ctr, sup)
+        return states, outs, self._make_stats(lat, ctr, sup)

@@ -53,6 +53,7 @@ import time
 from collections import deque
 
 from repro.graph.padding import promote_bucket_groups
+from repro.serve.spans import RunTrace
 from repro.serve.state_pool import TenantStatePool
 from repro.serve.supervision import TenantSupervisor
 
@@ -98,6 +99,18 @@ class ContinuousScheduler:
                     break
                 backlog[sid].append(item)
 
+    @staticmethod
+    def _ready(backlog, eof, active, sup: TenantSupervisor) -> list:
+        """Retire quarantined and fully served tenants from ``active``;
+        returns the tenants with snapshots ready."""
+        for sid in list(active):
+            if not sup.ok(sid):
+                backlog[sid].clear()
+                active.discard(sid)
+            elif sid in eof and not backlog[sid]:
+                active.discard(sid)  # stream fully served
+        return [sid for sid in active if backlog[sid]]
+
     # --------------------------------------------------------------- run ----
 
     def run(self, params, states: dict, streams: dict, *,
@@ -119,12 +132,12 @@ class ContinuousScheduler:
         sids = sorted(streams)
         x_sids = sorted(express_streams or {})
         x_set = set(x_sids)
-        t_start = time.perf_counter()
-        srv._t0_run, srv._commit_ms = t_start, {}
-        qs, pre_ms, stop, threads = srv._spawn_producers(streams)
+        srv._trace = RunTrace()
+        span = srv._trace.span
+        qs, stop, threads = srv._spawn_producers(streams)
         if x_sids:
             xqs, x_threads = srv._spawn_express_producers(
-                express_streams, stop, pre_ms)
+                express_streams, stop)
             qs = {**qs, **xqs}
             threads = threads + x_threads
         outs: dict = {sid: [] for sid in sids + x_sids}
@@ -142,17 +155,18 @@ class ContinuousScheduler:
         try:
             with srv._fault_window():
                 while active:
-                    self._admit(qs, backlog, eof, active, sup)
-                    for sid in list(active):
-                        if not sup.ok(sid):
-                            backlog[sid].clear()
-                            active.discard(sid)
-                        elif sid in eof and not backlog[sid]:
-                            active.discard(sid)  # stream fully served
-                    ready = [sid for sid in active if backlog[sid]]
+                    with span("serve.admit"):
+                        self._admit(qs, backlog, eof, active, sup)
+                    ready = self._ready(backlog, eof, active, sup)
+                    if not ready and active:
+                        # one span for the whole stretch of idle polls
+                        with span("serve.idle"):
+                            while not ready and active:
+                                time.sleep(_IDLE_SLEEP_S)
+                                self._admit(qs, backlog, eof, active, sup)
+                                ready = self._ready(backlog, eof, active,
+                                                    sup)
                     if not ready:
-                        if active:
-                            time.sleep(_IDLE_SLEEP_S)
                         continue
                     # fairness under pool pressure: least-recently-
                     # scheduled first. Only RECURRENT tenants compete for
@@ -198,7 +212,8 @@ class ContinuousScheduler:
                         last_tick[sid] = tick_no
                     # page the tick's working set in BEFORE any checkpoint
                     # is taken; evicts LRU tenants outside the set
-                    pool.acquire(list(chunks))
+                    with span("serve.pool"):
+                        pool.acquire(list(chunks))
                     groups: dict = {}
                     for sid, (chunk, dims) in sorted(chunks.items()):
                         bucket = srv._chunk_bucket(dims)
@@ -220,7 +235,7 @@ class ContinuousScheduler:
         finally:
             # every tenant's state returns device-resident, wherever its
             # pages lived mid-run; then deterministic producer shutdown
-            pool.flush()
+            with span("serve.pool"):
+                pool.flush()
             srv._shutdown(stop, list(qs.values()), threads)
-        total = (time.perf_counter() - t_start) * 1e3
-        return states, outs, srv._make_stats(lat, pre_ms, total, ctr, sup)
+        return states, outs, srv._make_stats(lat, ctr, sup)
