@@ -163,7 +163,8 @@ def serve_phase(cfg, tg, feat, streams: dict, seed: int) -> None:
           f"degraded {stats.degraded_launches}; cold {cold_s:.2f} s "
           f"(compile + run); repeated serve_multi {repeat_ms:.3f} "
           f"ms/snapshot (retraces); steady {steady_ms:.3f} ms/snapshot "
-          f"(staging {stats.stage_ms_per_snapshot:.3f}, device wait "
+          f"(staging {stats.stage_ms_per_snapshot:.3f}, "
+          f"{stats.stage_in_place_pct:.0f}% of chunks in place, device wait "
           f"{stats.device_wait_ms_per_snapshot:.3f} ms/snapshot); max error "
           f"vs baseline {err:.3e} (tol {TOL:g} x max(1, |baseline|); "
           f"{max_err(outs, want):.3e} at default matmul precision); "

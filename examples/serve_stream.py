@@ -114,6 +114,7 @@ def main():
     served = sum(len(v) for v in outs.values())
     print(f"multi-tenant v3: {len(streams)} clients, {served} snapshots in "
           f"{dt*1e3:.1f} ms (staging {stats.stage_ms_per_snapshot:.3f}, "
+          f"{stats.stage_in_place_pct:.0f}% of chunks in place, "
           f"device wait {stats.device_wait_ms_per_snapshot:.3f} "
           f"ms/snapshot, host prep overlapped across {len(streams)} "
           f"producer threads)")

@@ -63,6 +63,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.dgnn import DGNNConfig
 from repro.core.evolvegcn import EvolveGCN
@@ -232,8 +233,35 @@ def init_states_batched(model: Model, params, n_streams: int,
         lambda a: jnp.broadcast_to(a[None], (n_streams,) + a.shape), s0)
 
 
-def stack_time(padded_snaps: list) -> Any:
-    """Stack per-step PaddedSnapshots (same bucket) along a leading T axis."""
-    import numpy as np
+def _consecutive_rows(xs: tuple):
+    """``base[i:i + len(xs)]``, a view, when ``xs`` are the rows i, i+1, ...
+    of one C-contiguous array ``base`` in order; else None."""
+    base = getattr(xs[0], "base", None)
+    if (not isinstance(base, np.ndarray) or base.ndim == 0 or base.size == 0
+            or not base.flags.c_contiguous):
+        return None
+    shape, strides, row = base.shape[1:], base.strides[1:], base.strides[0]
+    p0 = xs[0].ctypes.data
+    i, off = divmod(p0 - base.ctypes.data, row)
+    if off or i + len(xs) > base.shape[0]:
+        return None
+    for k, x in enumerate(xs):
+        if (getattr(x, "base", None) is not base or x.shape != shape
+                or x.strides != strides or x.dtype != base.dtype
+                or x.ctypes.data != p0 + k * row):
+            return None
+    return base[i:i + len(xs)]
 
-    return jax.tree.map(lambda *xs: np.stack(xs, axis=0), *padded_snaps)
+
+def stack_time(padded_snaps: list) -> Any:
+    """Stack per-step PaddedSnapshots (same bucket) along a leading T axis.
+
+    A leaf whose steps are consecutive rows of one array, in order (the
+    serve producers pad each chunk into the rows of a
+    ``graph.padding.chunk_slab``), comes back as a view of those rows with
+    no copy; any other leaf is copied by ``np.stack``."""
+    def leaf(*xs):
+        rows = _consecutive_rows(xs)
+        return np.stack(xs, axis=0) if rows is None else rows
+
+    return jax.tree.map(leaf, *padded_snaps)

@@ -68,7 +68,8 @@ def renumber_and_normalize(snap: COOSnapshot, symmetric: bool = True) -> LocalSn
     )
 
 
-def to_ell(ls: LocalSnapshot, n_pad: int, k_max: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+def to_ell(ls: LocalSnapshot, n_pad: int, k_max: int, out: tuple | None = None
+           ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Padded neighbor lists: for each dst node, up to k_max (src, coef).
 
     Returns (neigh_idx (n_pad, k_max) int32, neigh_coef (n_pad, k_max) f32,
@@ -81,10 +82,18 @@ def to_ell(ls: LocalSnapshot, n_pad: int, k_max: int) -> tuple[np.ndarray, np.nd
     producer thread, so a per-edge Python loop here throttles the §IV-D
     host/device overlap the engine is built around. Slot order per dst is
     original edge order (stable sort), identical to the sequential fill.
+
+    ``out`` is an optional ``(idx, coe, eid)`` of writable arrays of those
+    shapes to fill in place (every element is written) and return.
     """
-    idx = np.zeros((n_pad, k_max), np.int32)
-    coe = np.zeros((n_pad, k_max), np.float32)
-    eid = np.zeros((n_pad, k_max), np.int32)
+    if out is None:
+        out = (np.zeros((n_pad, k_max), np.int32),
+               np.zeros((n_pad, k_max), np.float32),
+               np.zeros((n_pad, k_max), np.int32))
+    else:
+        for a in out:
+            a[...] = 0
+    idx, coe, eid = out
     e = ls.src.shape[0]
     if e == 0:
         return idx, coe, eid

@@ -80,41 +80,81 @@ class PaddedSnapshot:
         return self.neigh_idx.shape[1]
 
 
+def _alloc_padded(lead: tuple, n_pad: int, e_pad: int, k_max: int,
+                  din: int, de: int) -> PaddedSnapshot:
+    """Uninitialised PaddedSnapshot leaves, each with ``lead`` axes first."""
+    e, n = lead + (e_pad,), lead + (n_pad,)
+    return PaddedSnapshot(
+        src=np.empty(e, np.int32), dst=np.empty(e, np.int32),
+        coef=np.empty(e, np.float32),
+        edge_feat=np.empty(e + (de,), np.float32),
+        neigh_idx=np.empty(n + (k_max,), np.int32),
+        neigh_coef=np.empty(n + (k_max,), np.float32),
+        neigh_eidx=np.empty(n + (k_max,), np.int32),
+        node_feat=np.empty(n + (din,), np.float32),
+        node_mask=np.empty(n, np.float32), renumber=np.empty(n, np.int32),
+        n_nodes=np.empty(lead, np.int32), n_edges=np.empty(lead, np.int32))
+
+
+def chunk_slab(depth: int, n_pad: int, e_pad: int, k_max: int, din: int,
+               de: int) -> PaddedSnapshot:
+    """Uninitialised room for ``depth`` padded snapshots of one bucket:
+    every leaf has a leading ``depth`` axis (``n_nodes``/``n_edges`` are
+    ``(depth,)``). Pad into its rows with ``pad_snapshot(out=slab_row(...))``;
+    consecutive rows then stack along T as a view (``core.stack_time``)."""
+    return _alloc_padded((depth,), n_pad, e_pad, k_max, din, de)
+
+
+def slab_row(slab: PaddedSnapshot, i: int) -> PaddedSnapshot:
+    """Row ``i`` of a ``chunk_slab``: a PaddedSnapshot of writable views
+    (0-d ``n_nodes``/``n_edges``)."""
+    return jax.tree.map(lambda a: a[i, ...], slab)
+
+
 def pad_snapshot(
     ls: LocalSnapshot,
     feat_table: np.ndarray,
     n_pad: int,
     e_pad: int,
     k_max: int,
+    out: PaddedSnapshot | None = None,
 ) -> PaddedSnapshot:
     """Pad a renumbered snapshot into the (n_pad, e_pad, k_max) bucket.
 
     ``feat_table`` is the global node-feature store (G, Din); the renumber
     table selects the active rows — the paper's DRAM->BRAM load, guided by
     the renumber table.
+
+    ``out`` is an optional destination in that bucket (a ``slab_row``):
+    every element of it is written, padding included, and it is returned.
+    Without it the snapshot gets arrays of its own.
     """
     n, e = ls.n_nodes, ls.src.shape[0]
     if n > n_pad or e > e_pad:
         raise ValueError(f"snapshot ({n},{e}) exceeds bucket ({n_pad},{e_pad})")
-    de = ls.edge_feat.shape[1]
-    src = np.full(e_pad, n_pad - 1, np.int32)
-    dst = np.full(e_pad, n_pad - 1, np.int32)
-    coef = np.zeros(e_pad, np.float32)
-    ef = np.zeros((e_pad, de), np.float32)
-    src[:e], dst[:e], coef[:e], ef[:e] = ls.src, ls.dst, ls.coef, ls.edge_feat
-    nidx, ncoe, neid = to_ell(ls, n_pad, k_max)
-    nf = np.zeros((n_pad, feat_table.shape[1]), np.float32)
-    nf[:n] = feat_table[ls.renumber]
-    mask = np.zeros(n_pad, np.float32)
-    mask[:n] = 1.0
-    ren = np.full(n_pad, -1, np.int32)
-    ren[:n] = ls.renumber
-    return PaddedSnapshot(
-        src=src, dst=dst, coef=coef, edge_feat=ef,
-        neigh_idx=nidx, neigh_coef=ncoe, neigh_eidx=neid,
-        node_feat=nf, node_mask=mask, renumber=ren,
-        n_nodes=np.int32(n), n_edges=np.int32(e),
-    )
+    if out is None:
+        out = _alloc_padded((), n_pad, e_pad, k_max, feat_table.shape[1],
+                            ls.edge_feat.shape[1])
+    elif (out.n_pad, out.e_pad, out.k_max) != (n_pad, e_pad, k_max):
+        raise ValueError(f"out bucket ({out.n_pad},{out.e_pad},{out.k_max}) "
+                         f"is not ({n_pad},{e_pad},{k_max})")
+    # padded edges point at the sink row with coef 0
+    for a, live, pad in ((out.src, ls.src, n_pad - 1),
+                         (out.dst, ls.dst, n_pad - 1), (out.coef, ls.coef, 0),
+                         (out.edge_feat, ls.edge_feat, 0)):
+        a[:e] = live
+        a[e:] = pad
+    to_ell(ls, n_pad, k_max, out=(out.neigh_idx, out.neigh_coef,
+                                  out.neigh_eidx))
+    out.node_feat[:n] = feat_table[ls.renumber]
+    out.node_feat[n:] = 0
+    out.node_mask[:n] = 1.0
+    out.node_mask[n:] = 0
+    out.renumber[:n] = ls.renumber
+    out.renumber[n:] = -1
+    out.n_nodes[...] = n
+    out.n_edges[...] = e
+    return out
 
 
 def empty_padded(n_pad: int, e_pad: int, k_max: int, din: int,

@@ -54,7 +54,8 @@ def main() -> None:
     params, state = srv.init(jax.random.PRNGKey(0))
     _, outs, stats = srv.run(params, state, snaps)
     print(f"{name} ({srv.mode}) on {ds.name}: {len(outs)} snapshots, "
-          f"{stats.stage_ms_per_snapshot:.3f} ms/snapshot host staging, "
+          f"{stats.stage_ms_per_snapshot:.3f} ms/snapshot host staging "
+          f"({stats.stage_in_place_pct:.0f}% of chunks stacked in place), "
           f"{stats.device_wait_ms_per_snapshot:.3f} ms device wait, "
           f"{np.mean(stats.preprocess_ms):.3f} ms host (overlapped), "
           f"{stats.total_ms:.1f} ms total")

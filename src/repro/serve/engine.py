@@ -103,10 +103,12 @@ from repro.graph.padding import (
     bucket_cost,
     choose_bucket,
     choose_bucket_batch,
+    chunk_slab,
     empty_padded,
     pad_snapshot,
     pow2_target,
     promote_bucket_groups,
+    slab_row,
     stack_streams,
 )
 from repro.kernels import ops as kops
@@ -177,6 +179,10 @@ class ServeStats:
     # producer-thread CPU time of each preprocess_ms entry (the wall time
     # less the waits for the GIL)
     preprocess_cpu_ms: list = field(default_factory=list)
+    # stream chunks staged for a launch (per attempt), and those whose
+    # (T, ...) time stack was a view of a producer's chunk slab, not a copy
+    staged_chunks: int = 0
+    staged_in_place: int = 0
 
     def _per_snapshot(self, *names: str) -> float:
         n = len(self.per_snapshot_ms)  # one entry per served snapshot
@@ -189,6 +195,12 @@ class ServeStats:
         return self._per_snapshot("serve.stage", "serve.stack_batch")
 
     @property
+    def stage_in_place_pct(self) -> float:
+        """Share of the staged stream chunks stacked in place, in %."""
+        return (100.0 * self.staged_in_place / self.staged_chunks
+                if self.staged_chunks else 0.0)
+
+    @property
     def device_wait_ms_per_snapshot(self) -> float:
         """Wait on the device's result (``serve.device_wait``) per served
         snapshot."""
@@ -198,6 +210,37 @@ class ServeStats:
     def tenant_errors(self) -> dict:
         """{sid: error} for every quarantined tenant."""
         return {sid: r.error for sid, r in self.tenants.items() if not r.ok}
+
+
+@jax.jit
+def _stack_states(states: tuple):
+    """The launch's tenant states stacked on a leading B axis in one
+    dispatch; op by op it took one for each row and leaf, and each gives
+    up the GIL to the producer threads."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+
+
+class _ChunkSlabs:
+    """One producer's chunk slabs: snapshot j of its stream is padded into
+    row ``j % depth`` of a slab (``graph.padding.chunk_slab``) made fresh
+    every ``depth`` snapshots. A chunk the loop pulls in steps of ``depth``
+    from the stream's start is then consecutive rows of one slab, which
+    ``stack_time`` takes as a view: the loop thread copies nothing on the
+    T axis. Slabs are never reused, so a chunk's rows keep their slab alive
+    and unchanged for as long as a launch or a retry reads them."""
+
+    def __init__(self, depth: int):
+        self.depth = depth
+        self.slab, self.shape, self.j = None, None, 0
+
+    def row(self, *shape) -> PaddedSnapshot:
+        """The next row, for a snapshot of ``shape`` = (n_pad, e_pad,
+        k_max, din, de)."""
+        if self.slab is None or self.j == self.depth or shape != self.shape:
+            self.slab = chunk_slab(self.depth, *shape)
+            self.shape, self.j = shape, 0
+        self.j += 1
+        return slab_row(self.slab, self.j - 1)
 
 
 class SnapshotServer:
@@ -401,7 +444,8 @@ class SnapshotServer:
     # ------------------------------------------------------ host thread ----
 
     def _prepare(self, snap: COOSnapshot, tenant=SOLO_SID, *, feat=None,
-                 pad: Optional[tuple] = None, defer: bool = False) -> tuple:
+                 pad: Optional[tuple] = None, defer: bool = False,
+                 slabs: Optional[_ChunkSlabs] = None) -> tuple:
         """Host prep of one snapshot: validate, renumber + normalize,
         choose the bucket, pad. Returns ``(snapshot, (n, e, k) dims)``.
 
@@ -411,28 +455,34 @@ class SnapshotServer:
         static PER BUCKET: one compiled step per bucket in the jit cache.
         ``pad`` fixes the bucket (the express lane's); ``defer`` leaves a
         bucketed snapshot unpadded, for the device loop to pad once the
-        chunk's bucket — the max over its members — is known."""
+        chunk's bucket — the max over its members — is known. Where the
+        bucket is fixed for the whole stream, the snapshot is padded into
+        the next row of the producer's ``slabs``."""
         feat = self.feat_table if feat is None else feat
         self._probe("preprocess", tenant=tenant)
         validate_snapshot(snap, feat.shape[0], tenant=tenant)
         ls = renumber_and_normalize(snap)
         dims = (ls.n_nodes, ls.src.shape[0], max_in_degree(ls))
+        out = None
         if pad is None and self.buckets is not None:
             self._probe("bucket", tenant=tenant)
             pad = choose_bucket(*dims, self.buckets)  # fails fast on no fit
             if defer:
                 return ls, dims
-        elif pad is None:
+        else:
             # fixed bucket known up front: pad here so the host prep fully
             # overlaps device work
-            pad = (self.n_pad, self.e_pad, self.k_max)
-        return pad_snapshot(ls, feat, *pad), dims
+            pad = pad or (self.n_pad, self.e_pad, self.k_max)
+            if slabs is not None:
+                out = slabs.row(*pad, feat.shape[1], ls.edge_feat.shape[1])
+        return pad_snapshot(ls, feat, *pad, out=out), dims
 
     def _start_producer(self, sid, snaps: Iterable[COOSnapshot],
                         q: queue.Queue, stop: threading.Event, prepare,
                         name: str) -> threading.Thread:
-        """Start one host prep thread: ``prepare(snapshot, sid)`` each
-        snapshot of ``snaps`` under a ``serve.prep`` span, stamp its
+        """Start one host prep thread: ``prepare(snapshot, sid, slabs)``
+        each snapshot of ``snaps`` under a ``serve.prep`` span (``slabs``:
+        the thread's ``_ChunkSlabs``), stamp its
         arrival and readiness, and put the result on ``q`` in stream
         order; then ``None`` at end-of-stream — or the ``BaseException``
         the producer failed with (validation, no-fit bucket, injected
@@ -453,12 +503,13 @@ class SnapshotServer:
             return False
 
         def producer():
+            slabs = _ChunkSlabs(self.stream_chunk)
             try:
                 for s in snaps:
                     t_arrive = trace.now_ms()
                     c0 = time.thread_time()
                     with trace.span("serve.prep", tenant=sid) as sp:
-                        item = prepare(s, sid)
+                        item = prepare(s, sid, slabs)
                     trace.prep_cpu_ms.append((time.thread_time() - c0) * 1e3)
                     trace.prep_ms.append(sp.ms)
                     arrive.append(t_arrive)
@@ -548,7 +599,7 @@ class SnapshotServer:
         bf[family] = bf.get(family, 0) + 1
         return ctr["launches"]
 
-    def _stage_group(self, params, states: dict, group: list,
+    def _stage_group(self, params, states: dict, group: list, ctr: dict,
                      force_ref: bool = False, launch: int = 0) -> tuple:
         """Launch one batched V3 group WITHOUT committing anything: build
         the (B, T) batch, run it, and return the staged per-tenant results
@@ -576,7 +627,9 @@ class SnapshotServer:
         The phases run under the spans ``serve.stage`` (everything before
         the timed launch), ``serve.stack_batch``, ``serve.dispatch`` and
         ``serve.device_wait`` (the timed launch wall) and
-        ``serve.unstage``, each with the ``launch`` index.
+        ``serve.unstage``, each with the ``launch`` index. ``ctr`` counts
+        the chunks staged and those whose time stack is a view of a
+        producer's chunk slab (``staged``, ``in_place``).
         """
         span = self._trace.span
         bucket = group[0][2]
@@ -586,6 +639,7 @@ class SnapshotServer:
         b_target = pow2_target(b_real)
         with span("serve.stage", launch=launch, B=b_target, T=target):
             per_stream = []
+            in_place = 0
             for _, chunk, _ in group:
                 # fixed-bucket items arrive pre-padded from the producer
                 # thread (host-prep overlap); bucketed items pad here, once
@@ -596,16 +650,24 @@ class SnapshotServer:
                 # ragged T: tail slots repeat the last snapshot — dead
                 # ``lengths`` slots, masked in-launch, content irrelevant
                 padded = padded + [padded[-1]] * (target - len(padded))
-                per_stream.append(stack_time(padded))
+                stacked = stack_time(padded)
+                # a view of the slab shares its rows' base (a copy has none);
+                # no numpy call here, as each one would give up the GIL
+                in_place += all(a.base is not None and a.base is b.base
+                                for a, b in zip(jax.tree.leaves(stacked),
+                                                jax.tree.leaves(padded[0])))
+                per_stream.append(stacked)
+            ctr["staged"] = ctr.get("staged", 0) + b_real
+            ctr["in_place"] = ctr.get("in_place", 0) + in_place
             # batch-axis padding = length-0 streams (results discarded)
             per_stream.extend([per_stream[0]] * (b_target - b_real))
             lengths = np.asarray(real_lens + [0] * (b_target - b_real),
                                  np.int32)
-            zero_state = jax.tree.map(jnp.zeros_like, states[group[0][0]])
-            states_B = jax.tree.map(
-                lambda *xs: jnp.stack(xs, axis=0),
-                *([states[sid] for sid, _, _ in group]
-                  + [zero_state] * (b_target - b_real)))
+            rows = [states[sid] for sid, _, _ in group]
+            if b_target > b_real:
+                zero_state = jax.tree.map(jnp.zeros_like, rows[0])
+                rows += [zero_state] * (b_target - b_real)
+            states_B = _stack_states(tuple(rows))
         key = (bucket, target, b_target, force_ref)
         warmed = key in self._warmed
         self._launch_ctx = tuple(sid for sid, _, _ in group)
@@ -690,7 +752,7 @@ class SnapshotServer:
                 with span("serve.checkpoint", launch=k):
                     ckpt = sup.checkpoint(states, sids)
             try:
-                staged = self._stage_group(params, states, members,
+                staged = self._stage_group(params, states, members, ctr,
                                            force_ref=force_ref, launch=k)
                 with span("serve.commit", launch=k):
                     self._commit_group(states, members, staged, outs, lat,
@@ -832,7 +894,9 @@ class SnapshotServer:
             **trace.stamps(),
             phase_ms=dict(trace.phase_ms),
             phase_n=dict(trace.phase_n),
-            preprocess_cpu_ms=list(trace.prep_cpu_ms))
+            preprocess_cpu_ms=list(trace.prep_cpu_ms),
+            staged_chunks=ctr.get("staged", 0),
+            staged_in_place=ctr.get("in_place", 0))
 
     def run(self, params, state, snaps: Iterable[COOSnapshot]) -> tuple:
         """Returns (final_state, outputs list, ServeStats).
@@ -856,7 +920,8 @@ class SnapshotServer:
         self._trace = RunTrace()
         span = self._trace.span
         th = self._start_producer(SOLO_SID, snaps, q, stop,
-                                  lambda s, sid: self._prepare(s, sid)[0],
+                                  lambda s, sid, slabs: self._prepare(
+                                      s, sid, slabs=slabs)[0],
                                   name=f"dgnn-serve-producer-{SOLO_SID}")
         outs: list = []
         lat: list = []
@@ -1017,7 +1082,8 @@ class SnapshotServer:
         stop = threading.Event()
         threads = [self._start_producer(
             sid, streams[sid], qs[sid], stop,
-            lambda s, sid: self._prepare(s, sid, defer=True),
+            lambda s, sid, slabs: self._prepare(s, sid, defer=True,
+                                                slabs=slabs),
             name=f"dgnn-serve-producer-{sid}") for sid in sids]
         return qs, stop, threads
 
@@ -1037,8 +1103,8 @@ class SnapshotServer:
               for sid in sids}
         threads = [self._start_producer(
             sid, streams[sid], qs[sid], stop,
-            lambda s, sid: self._prepare(s, sid, feat=self._express_feat,
-                                         pad=pad),
+            lambda s, sid, slabs: self._prepare(
+                s, sid, feat=self._express_feat, pad=pad, slabs=slabs),
             name=f"dgnn-serve-express-{sid}") for sid in sids]
         return qs, threads
 

@@ -1,8 +1,10 @@
 """Graph substrate: slicing, renumbering, format conversion, padding."""
+import jax
 import numpy as np
 import pytest
 
 from repro.configs.dgnn import BC_ALPHA, UCI
+from repro.core import stack_time
 from repro.graph import (
     choose_bucket,
     empty_like_padded,
@@ -14,6 +16,7 @@ from repro.graph import (
     snapshot_stats,
     to_ell,
 )
+from repro.graph.padding import chunk_slab, slab_row
 
 
 @pytest.fixture(scope="module")
@@ -138,3 +141,78 @@ def test_empty_like_padded_is_noop_snapshot(bc):
     assert np.all(np.asarray(empty.node_mask) == 0)
     assert np.all(np.asarray(empty.renumber) == -1)
     assert np.all(np.asarray(empty.neigh_coef) == 0)
+
+
+def _slab_rows(ft, lss, bucket=(640, 4096, 64)):
+    """``lss`` padded into the consecutive rows of one fresh chunk slab."""
+    slab = chunk_slab(len(lss), *bucket, ft.shape[1],
+                      lss[0].edge_feat.shape[1])
+    return [pad_snapshot(ls, ft, *bucket, out=slab_row(slab, i))
+            for i, ls in enumerate(lss)]
+
+
+# how each case picks the steps to stack from two slabs a and b of 8 rows
+# (and from snapshots padded on their own)
+STACK_CASES = {
+    "consecutive_slab_rows": lambda a, b, fresh: a[2:6],
+    "repeated_last_row": lambda a, b, fresh: a[:3] + [a[2]],
+    "rows_of_two_slabs": lambda a, b, fresh: a[6:] + b[:2],
+    "rows_out_of_order": lambda a, b, fresh: [a[1], a[0], a[2]],
+    "fresh_arrays": lambda a, b, fresh: fresh[:4],
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACK_CASES))
+def test_stack_time_views_consecutive_slab_rows_else_copies(bc, case):
+    """Consecutive rows of one slab, in order, stack along T as a view of
+    the slab; every other input is copied. Both equal ``np.stack`` leaf
+    for leaf."""
+    _, ft, snaps = bc
+    lss = [renumber_and_normalize(s) for s in snaps[:16]]
+    a, b = _slab_rows(ft, lss[:8]), _slab_rows(ft, lss[8:])
+    fresh = [pad_snapshot(ls, ft, 640, 4096, 64) for ls in lss[:4]]
+    steps = STACK_CASES[case](a, b, fresh)
+    got = jax.tree.leaves(stack_time(steps))
+    want = jax.tree.leaves(jax.tree.map(lambda *xs: np.stack(xs), *steps))
+    firsts = jax.tree.leaves(steps[0])
+    assert len(got) == len(want) == 12
+    for g, w, first in zip(got, want, firsts):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+        assert np.shares_memory(g, first) == (case == "consecutive_slab_rows")
+
+
+def test_pad_snapshot_into_a_dirty_slot_equals_a_fresh_pad(bc):
+    """A slot that held a larger snapshot, padded again with a smaller
+    one, equals a fresh ``pad_snapshot`` of the smaller one everywhere:
+    the sink-row ``src``/``dst``, the ``-1`` renumber and the zero masks
+    and coefficients of the padding included. The slab's other row is
+    left as it was."""
+    _, ft, snaps = bc
+    lss = sorted((renumber_and_normalize(s) for s in snaps),
+                 key=lambda ls: ls.src.shape[0])
+    small, big = lss[0], lss[-1]
+    assert big.src.shape[0] > small.src.shape[0]
+    assert big.n_nodes > small.n_nodes
+    slab = chunk_slab(2, 640, 4096, 64, ft.shape[1],
+                      small.edge_feat.shape[1])
+    for leaf in jax.tree.leaves(slab):
+        leaf[...] = 7  # no element may keep what the slab held
+    slot = slab_row(slab, 1)
+    pad_snapshot(big, ft, 640, 4096, 64, out=slot)
+    got = pad_snapshot(small, ft, 640, 4096, 64, out=slot)
+    assert got is slot
+    want = pad_snapshot(small, ft, 640, 4096, 64)
+    for name in ("src", "dst", "coef", "edge_feat", "neigh_idx",
+                 "neigh_coef", "neigh_eidx", "node_feat", "node_mask",
+                 "renumber", "n_nodes", "n_edges"):
+        g, w = getattr(got, name), getattr(want, name)
+        assert g.dtype == w.dtype, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    n, e = small.n_nodes, small.src.shape[0]
+    assert np.all(slot.src[e:] == 639) and np.all(slot.dst[e:] == 639)
+    assert np.all(slot.renumber[n:] == -1)
+    assert not slot.node_mask[n:].any() and not slot.coef[e:].any()
+    assert not slot.neigh_coef[n:].any()
+    for leaf in jax.tree.leaves(slab_row(slab, 0)):
+        assert np.all(leaf == 7)

@@ -429,6 +429,67 @@ def test_run_multi_evolvegcn_takes_batched_stream_path():
                         "no-op padding")
 
 
+def _serve_replay(streams, tg, ft, **plan_kw):
+    """GCRN-M2 v3 over ``streams`` with fresh states, ``stream_chunk`` 8:
+    ``(states, outs, stats)``."""
+    srv = SnapshotServer(GCRN_M2, ft, n_global=tg.n_global_nodes, mode="v3",
+                         stream_chunk=8, **plan_kw)
+    params, _ = srv.init(jax.random.PRNGKey(0))
+    states = {sid: srv.model.init_state(params, mode="v3") for sid in streams}
+    return srv.run_multi(params, states, streams)
+
+
+def _assert_same_serve(got, want):
+    """Outputs and final states of two serve runs are equal bit for bit."""
+    (g_states, g_outs, _), (w_states, w_outs, _) = got, want
+    assert g_outs.keys() == w_outs.keys()
+    for sid in w_outs:
+        assert len(g_outs[sid]) == len(w_outs[sid])
+        for t, (g, w) in enumerate(zip(g_outs[sid], w_outs[sid])):
+            np.testing.assert_array_equal(g, w, err_msg=f"{sid} t={t}")
+        for g, w in zip(jax.tree.leaves(g_states[sid]),
+                        jax.tree.leaves(w_states[sid])):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=f"{sid} final state")
+
+
+def test_replay_chunks_stage_in_place_and_match_copy_staging():
+    """Replay-shaped traffic (4 tenants x 16 snapshots, chunks of 8, the
+    rounds scheduler) with one fixed bucket: every chunk is consecutive
+    rows of its producer's slab, so every chunk stages as a view. Outputs
+    and final states equal, bit for bit, the same streams served through
+    a one-bucket ``buckets`` plan (padded on the loop thread and stacked
+    by copy) and through the continuous scheduler."""
+    tg, ft = generate_temporal_graph(UCI)
+    snaps = slice_snapshots(tg, 1.0)
+    streams = {f"t{i}": snaps[4 * i:4 * i + 16] for i in range(4)}
+    slabs = _serve_replay(streams, tg, ft)
+    stats = slabs[2]
+    assert (stats.staged_chunks, stats.staged_in_place) == (8, 8)
+    assert stats.stage_in_place_pct == 100.0
+    copied = _serve_replay(streams, tg, ft, buckets=((640, 4096, 64),))
+    assert copied[2].staged_chunks == 8
+    assert copied[2].stage_in_place_pct == 0.0
+    _assert_same_serve(slabs, copied)
+    _assert_same_serve(_serve_replay(streams, tg, ft, scheduler="continuous"),
+                       slabs)
+
+
+def test_ragged_tail_chunk_stages_by_copy():
+    """A 13-snapshot tenant's last chunk (5 live, padded to T=8 by
+    repeating its last row) is not a run of slab rows: it stages by copy,
+    so the in-place share falls below 100% while the other chunks stay
+    views."""
+    tg, ft = generate_temporal_graph(UCI)
+    snaps = slice_snapshots(tg, 1.0)
+    streams = {f"t{i}": snaps[4 * i:4 * i + 16] for i in range(3)}
+    streams["t3"] = snaps[12:25]
+    _, outs, stats = _serve_replay(streams, tg, ft)
+    assert len(outs["t3"]) == 13
+    assert (stats.staged_chunks, stats.staged_in_place) == (8, 7)
+    assert stats.stage_in_place_pct == 87.5
+
+
 def test_lm_generate_greedy_deterministic():
     import jax.numpy as jnp
 
