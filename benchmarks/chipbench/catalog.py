@@ -1,8 +1,9 @@
 """Finds everything a cell names, by name: ``BENCHMARK.json`` at the
 checkout's root, ``configs/<config>.json``, ``traffic/<mix>.json``,
-``metrics/<metric>.py``, ``reference/<family>.py``, ``work/<family>.py``
-and ``peaks.json`` beside this file. A new configuration, traffic mix or
-per-layer metric is a new file here and an entry in ``BENCHMARK.json``.
+``metrics/<metric>.py``, ``reference/<family>.py``, ``work/<family>.py``,
+``streams/<kind>.py`` and ``peaks.json`` beside this file. A new
+configuration, traffic mix, per-layer metric, model family or stream kind
+is a new file here and an entry in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
@@ -13,6 +14,8 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+#: the stream kind of a configuration whose ``dataset`` names none
+DEFAULT_KIND = "snapshots"
 
 
 def _json(path: Path) -> dict:
@@ -43,6 +46,12 @@ def family(name: str) -> tuple:
     """``(reference module, work module)`` of a model family."""
     return (importlib.import_module(f"chipbench.reference.{name}"),
             importlib.import_module(f"chipbench.work.{name}"))
+
+
+def stream_kind(name: str):
+    """The stream kind ``streams/<name>.py`` (see ``streams/__init__.py``),
+    found on the ``chipbench.streams`` package's search path."""
+    return importlib.import_module(f"chipbench.streams.{name}")
 
 
 def metric_reader(name: str):

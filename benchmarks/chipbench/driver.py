@@ -8,7 +8,7 @@ kept for the whole run, under the matrix-multiply precision that the
 configuration states. Two traffic modes, named by the mix's ``mode``:
 
 ``replay``     closed loop: passes of ``tenants`` streams, each a
-               ``history``-long run of the graph stream from a seeded
+               ``history``-long run of the cell's stream from a seeded
                offset with a fresh state, back to back until ``--seconds``
                have passed.
 ``open_loop``  one ``run_multi`` over the window: every tenant's snapshots
@@ -118,18 +118,24 @@ def failures(stats, outs: dict, expected: dict, chunk: int) -> int:
 
 
 class Stream:
-    """The seeded graph stream and what the harness knows of each
-    snapshot (active nodes, edges, work)."""
+    """A seeded stream of one kind (``streams/<kind>.py``) and what the
+    harness knows of each step: the server's item, active global rows,
+    live output rows, work."""
 
-    def __init__(self, snaps: list, work, m: dict):
-        self.snaps = snaps
-        self.active = [np.unique(np.concatenate([s, d])) for s, d, _ in snaps]
-        self.n = [a.size for a in self.active]
-        self.e = [s.size for s, _, _ in snaps]
-        self.flops = [work.snapshot_flops(m, n, e)
-                      for n, e in zip(self.n, self.e)]
-        self.bytes = [work.snapshot_bytes(m, n, e)
-                      for n, e in zip(self.n, self.e)]
+    def __init__(self, kind, data, work, m: dict):
+        self.kind, self.data = kind, data
+        self.n_global = data.n_global
+        steps = range(len(data))
+        self.items = [kind.item(data, k) for k in steps]
+        self.active = [kind.rows(data, k) for k in steps]
+        self.n = [kind.live_rows(data, k) for k in steps]
+        counts = [kind.work(data, k, work, m) for k in steps]
+        self.flops = [f for f, _ in counts]
+        self.bytes = [b for _, b in counts]
+
+    def prepare(self, k: int) -> dict:
+        """Step ``k``'s padded arrays for the reference."""
+        return self.kind.prepare(self.data, k)
 
     def keep(self, idx: list, outs: list) -> list:
         """Copies of a stream's served outputs: live rows, and the largest
@@ -146,10 +152,10 @@ class Stream:
 class Runner:
     """Traffic of one mix through one kept server."""
 
-    def __init__(self, server, params, fresh, coo: list, stream: Stream,
-                 mix: dict, seed: int, chunk: int):
+    def __init__(self, server, params, fresh, stream: Stream, mix: dict,
+                 seed: int, chunk: int):
         self.server, self.params, self.fresh = server, params, fresh
-        self.coo, self.stream, self.mix = coo, stream, mix
+        self.items, self.stream, self.mix = stream.items, stream, mix
         self.seed, self.chunk = seed, chunk
         self.retained: list = []  # ([sid,] stream indices, outputs, state)
 
@@ -165,10 +171,12 @@ class Replay(Runner):
         h = self.mix["history"]
         idx = {f"t{i:02d}": list(range(o, o + h))
                for i, o in enumerate(offsets)}
-        return idx, {sid: [self.coo[k] for k in ks] for sid, ks in idx.items()}
+        return idx, {sid: [self.items[k] for k in ks]
+                     for sid, ks in idx.items()}
 
     def _offsets(self, rng):
-        return traffic.replay_offsets(rng, len(self.coo), self.mix["tenants"],
+        return traffic.replay_offsets(rng, len(self.items),
+                                      self.mix["tenants"],
                                       self.mix["history"])
 
     def warm(self) -> None:
@@ -248,7 +256,7 @@ class OpenLoop(Runner):
                 lateness: list | None = None) -> tuple:
         """Serve one open-loop segment; returns ``(schedule, states,
         outs, stats, wall_s)``."""
-        n = len(self.coo)
+        n = len(self.items)
         sched = traffic.open_loop(rng, n, self.mix["tenants"], rate, seconds,
                                   self.mix["zipf_s"])
         origin = [0.0]
@@ -261,7 +269,7 @@ class OpenLoop(Runner):
                     time.sleep(wait)
                 if lateness is not None:
                     lateness.append(1e3 * (time.perf_counter() - target))
-                yield self.coo[(off + k) % n]
+                yield self.items[(off + k) % n]
 
         streams = {f"t{i:02d}": arrivals(off, due)
                    for i, (off, due) in enumerate(sched)}
@@ -280,7 +288,7 @@ class OpenLoop(Runner):
         wave also composes smaller launches); then segments of the
         cell's own traffic (at ``rate``, by default the mix's) until one
         lowers nothing new."""
-        tenants, n = self.mix["tenants"], len(self.coo)
+        tenants, n = self.mix["tenants"], len(self.items)
         counter = lowerings()
         for _ in range(self.WARM_ROUNDS_MAX):
             before = counter.count
@@ -289,7 +297,7 @@ class OpenLoop(Runner):
                 b = 1
                 while b < 2 * tenants:
                     b = min(b, tenants)
-                    self.serve({f"t{i:02d}": [self.coo[(i + k) % n]
+                    self.serve({f"t{i:02d}": [self.items[(i + k) % n]
                                               for k in range(t)]
                                 for i in range(b)})
                     b *= 2
@@ -321,7 +329,7 @@ class OpenLoop(Runner):
         longest = int(np.argmax(counts))
         others = [i for i in traffic.rng_for(self.seed, 5).permutation(
             len(sched)) if i != longest and counts[i] > 0]
-        n = len(self.coo)
+        n = len(self.items)
         for i in [longest] + others[:self.mix["check_streams"] - 1]:
             sid = f"t{i:02d}"
             idx = traffic.walk(n, sched[i][0], len(outs[sid]))
@@ -391,8 +399,7 @@ def gaps(outs: list, state, r_outs: list, r_state) -> dict:
 NUMBERS = ("out_gap", "state_gap", "out_rms_gap", "state_rms_gap")
 
 
-def compare(ref, params, m: dict, n_global: int, stream: Stream,
-            feat: np.ndarray, bucket: tuple, sample: list,
+def compare(ref, params, m: dict, stream: Stream, sample: list,
             control: bool = False) -> dict:
     """The numbers that decide ``correct``, over the sampled streams,
     program against the reference at full float32 precision: the widest
@@ -408,8 +415,7 @@ def compare(ref, params, m: dict, n_global: int, stream: Stream,
 
     def prepared(k):
         if k not in cache:
-            cache[k] = refcore.prepare(*stream.snaps[k], *bucket[:2],
-                                       n_global)
+            cache[k] = stream.prepare(k)
         return cache[k]
 
     def fold(prefix, numbers):
@@ -417,17 +423,18 @@ def compare(ref, params, m: dict, n_global: int, stream: Stream,
             out[prefix + k] = max(out[prefix + k], v)
 
     for idx, kept, state in sample:
-        graphs = [prepared(k) for k in idx]
-        r_outs, r_state = refcore.run_stream(ref, params, m, n_global,
-                                             graphs, feat, "highest")
+        steps = [prepared(k) for k in idx]
+        r_outs, r_state = refcore.run_stream(ref, params, m, stream.n_global,
+                                             steps, "highest")
         got = gaps([live for live, _ in kept], state, r_outs, r_state)
         for (_, pad), want in zip(kept, r_outs):
             scale = max(1.0, float(np.abs(want).max())) if want.size else 1.0
             got["out_gap"] = max(got["out_gap"], pad / scale)
         fold("", got)
         if control:
-            c_outs, c_state = refcore.run_stream(ref, params, m, n_global,
-                                                 graphs, feat, "high")
+            c_outs, c_state = refcore.run_stream(ref, params, m,
+                                                 stream.n_global, steps,
+                                                 "high")
             fold("control_", gaps(c_outs, c_state, r_outs, r_state))
     return out
 
@@ -447,6 +454,12 @@ def control_numbers(check: dict) -> dict:
     return {k: check[f"control_{k}"] for k in NUMBERS}
 
 
+def memory_peak(devices: list) -> int:
+    """The peak bytes in use on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
+
+
 def program_files() -> frozenset:
     import repro
 
@@ -455,26 +468,24 @@ def program_files() -> frozenset:
 
 
 class Cell:
-    """A cell built for one seed: its graph stream, weights, and one
-    kept server behind a runner of the cell's traffic mode. Everything
-    that touches the server runs under ``with cell.precision():``."""
+    """A cell built for one seed: its stream (of the configuration's
+    kind), weights, and one kept server behind a runner of the cell's
+    traffic mode. Everything that touches the server runs under ``with
+    cell.precision():``."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int):
         from repro.api import BoosterSession, plan
         from repro.configs.dgnn import DGNNConfig
-        from repro.graph.coo import COOSnapshot
         from repro.serve import SnapshotServer
 
         self.cfg, self.mix, self.seed = cfg, mix, seed
         self.ref, self.work = catalog.family(cfg["family"])
         self.m = m = cfg["model"]
         fields = {**cfg["plan"], **mix.get("plan", {})}
-        self.bucket = (fields["n_pad"], fields["e_pad"], fields["k_max"])
-        snaps, self.feat, self.n_global = traffic.graph_stream(
-            cfg["dataset"], seed, m["in_dim"], m["edge_dim"], self.bucket)
-        self.stream = Stream(snaps, self.work, m)
-        coo = [COOSnapshot(src=s, dst=d, edge_feat=e, t_index=i)
-               for i, (s, d, e) in enumerate(snaps)]
+        kind = catalog.stream_kind(cfg["dataset"].get("kind",
+                                                      catalog.DEFAULT_KIND))
+        data = kind.build(cfg, fields, seed)
+        self.stream = Stream(kind, data, self.work, m)
         key = jax.random.key(int(np.random.SeedSequence([seed, 1])
                                  .generate_state(1)[0]))
         with self.precision():
@@ -482,20 +493,19 @@ class Cell:
                 lambda k: self.ref.make_params(k, m))(key)
             dcfg = DGNNConfig(**m)
             session = BoosterSession(dcfg, plan(dcfg, **fields),
-                                     n_global=self.n_global,
-                                     feat_table=self.feat,
+                                     n_global=data.n_global,
+                                     feat_table=data.feat,
                                      params=self.params)
             self.runner = RUNNERS[mix["mode"]](
                 SnapshotServer(session=session), self.params,
-                session.reset_state(), coo, self.stream, mix, seed,
+                session.reset_state(), self.stream, mix, seed,
                 fields["stream_chunk"])
 
     def precision(self):
         return jax.default_matmul_precision(self.cfg["matmul_precision"])
 
     def check(self, sample: list, control: bool = False) -> dict:
-        return compare(self.ref, self.params, self.m, self.n_global,
-                       self.stream, self.feat, self.bucket, sample,
+        return compare(self.ref, self.params, self.m, self.stream, sample,
                        control=control)
 
 
@@ -505,7 +515,8 @@ def run_cell(cfg: dict, mix: dict, seed: int, seconds: float, trace: bool,
     """Run one cell; returns ``(record, check, device)``: the measured
     record, the compared numbers, and the device description of the
     result line."""
-    dev = jax.devices()[0]
+    devs = jax.devices()
+    dev = devs[0]
     rec = Record(mode=mix["mode"],
                  peaks=peaks if peaks is not None else catalog.peaks(
                      dev.device_kind))
@@ -532,10 +543,8 @@ def run_cell(cfg: dict, mix: dict, seed: int, seconds: float, trace: bool,
             if rec.trace["window_s"] is None:
                 rec.trace["window_s"] = t_slice
             shutil.rmtree(trace_dir, ignore_errors=True)
-    stats = dev.memory_stats() or {}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()),
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+              "count": len(devs), "memory_peak_bytes": memory_peak(devs)}
     if trace:
         device["busy_s"] = rec.trace["busy_s"]
         device["window_s"] = rec.trace["window_s"]

@@ -1,15 +1,11 @@
-"""Driver of the plain references: one tenant's snapshot stream, step by
-step, in float32 ``jax.numpy``.
+"""Driver of the plain references: one tenant's stream, step by step, in
+float32 ``jax.numpy``.
 
 Imports nothing of the program. Each family's equations live in
 ``reference/<family>.py`` as ``step(params, state, g, m, dot)`` over one
-snapshot ``g``, prepared here from its raw COO edges: active nodes
-renumbered in ascending global id, edges made undirected, a self-loop per
-node, and the symmetric GCN coefficient ``1 / sqrt(deg(u) deg(v))`` over
-in-degrees of that graph. The arrays are padded to fixed sizes only so
-that one compiled scan serves every stream: padding edges carry
-coefficient 0 into a spare segment, padding nodes read and write a spare
-store row, and neither reaches a compared number.
+step ``g``, the padded arrays that the stream's kind prepares
+(``streams/<kind>.py``). The padding serves one compiled scan for every
+stream and reaches no compared number.
 
 ``dot`` is the precision under test: ``"highest"`` is the reference,
 ``"high"`` the control, which rounds each operand to a bfloat16 pair and
@@ -52,47 +48,6 @@ def dot_fn(precision: str):
     raise ValueError(f"unknown reference precision {precision!r}")
 
 
-def prepare(src: np.ndarray, dst: np.ndarray, edge_feat: np.ndarray,
-            n_rows: int, n_edges: int, sink: int) -> dict:
-    """One snapshot's padded reference arrays (numpy), from global-id COO
-    edges. ``sink`` is the spare store row that padding nodes use."""
-    active = np.unique(np.concatenate([src, dst]))
-    n = active.size
-    s = np.searchsorted(active, src)
-    d = np.searchsorted(active, dst)
-    loops = np.arange(n)
-    es = np.concatenate([s, d, loops])
-    ed = np.concatenate([d, s, loops])
-    ef = np.concatenate([edge_feat, edge_feat,
-                         np.zeros((n, edge_feat.shape[1]), np.float32)])
-    deg = np.bincount(ed, minlength=n).astype(np.float64)
-    coef = 1.0 / np.sqrt(deg[es] * deg[ed])
-    m = es.size
-    if n > n_rows or m > n_edges:
-        raise ValueError(f"snapshot of {n} nodes / {m} edges exceeds the "
-                         f"reference buffers ({n_rows}, {n_edges})")
-    g = {"rows": np.full(n_rows, sink, np.int32),
-         "src": np.zeros(n_edges, np.int32),
-         "dst": np.full(n_edges, n_rows, np.int32),
-         "coef": np.zeros(n_edges, np.float32),
-         "ef": np.zeros((n_edges, edge_feat.shape[1]), np.float32),
-         "live": np.int32(1)}
-    g["rows"][:n] = active
-    g["n"] = n
-    g["src"][:m], g["dst"][:m] = es, ed
-    g["coef"][:m], g["ef"][:m] = coef, ef
-    return g
-
-
-def max_in_degree(src: np.ndarray, dst: np.ndarray) -> int:
-    """Largest in-degree of the undirected graph with self-loops."""
-    active = np.unique(np.concatenate([src, dst]))
-    s = np.searchsorted(active, src)
-    d = np.searchsorted(active, dst)
-    return int(np.bincount(np.concatenate([d, s]),
-                           minlength=active.size).max()) + 1
-
-
 def aggregate(g: dict, msg: jax.Array) -> jax.Array:
     """``sum over edges (u -> v) of coef * msg[u -> v]`` for every node v,
     from one message per edge."""
@@ -122,12 +77,12 @@ def _block_fn(family, dims: tuple, precision: str):
 
 
 def run_stream(family, params, m: dict, n_global: int, prepared: list,
-               feat: np.ndarray, precision: str):
+               precision: str):
     """One tenant's stream through ``family``'s reference from a fresh
-    state. ``prepared`` holds the stream's snapshots as ``prepare`` made
-    them, ``feat`` is the global node-feature table. Returns
-    ``(outputs, final_state)``: outputs a list of ``(n_active, out_dim)``
-    numpy arrays, the state in the program's tree, as numpy."""
+    state. ``prepared`` holds the stream's steps as its kind's ``prepare``
+    made them. Returns ``(outputs, final_state)``: outputs a list of
+    ``(n, out_dim)`` numpy arrays (``n`` the step's live output rows),
+    the state in the program's tree, as numpy."""
     fn = _block_fn(family, tuple(sorted(m.items())), precision)
     state = family.to_store(
         family.init_state(params, m, n_global, dot_fn(precision)))
@@ -137,7 +92,6 @@ def run_stream(family, params, m: dict, n_global: int, prepared: list,
         blk = blk + [dict(blk[-1], live=np.int32(0))] * (BLOCK - len(blk))
         stacked = {k: np.stack([g[k] for g in blk]) for k in blk[0]
                    if k != "n"}
-        stacked["x"] = feat[np.minimum(stacked["rows"], n_global - 1)]
         state, o = fn(params, state, stacked)
         o = np.asarray(o)
         outs.extend(o[i, :g["n"]]

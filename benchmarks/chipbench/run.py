@@ -4,7 +4,7 @@
     python3 benchmarks/chipbench/run.py --workload <cell> --seed <n> \\
         --seconds <s> --trace <0|1>
 
-Builds the cell's graph stream, weights and traffic from ``--seed``, warms
+Builds the cell's stream, weights and traffic from ``--seed``, warms
 the kept server, measures for ``--seconds``, then (``--trace 1``) traces a
 short slice, and compares what the window served with the plain
 reference. The last line of standard output is one JSON object:

@@ -10,9 +10,9 @@ the reference checks, and each planted fault in the timed path must turn
 from __future__ import annotations
 
 import copy
-import json
 import os
 import re
+import shutil
 import subprocess
 import sys
 import time
@@ -25,12 +25,22 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[2]
 sys.path[:0] = [str(ROOT / "src"), str(ROOT / "benchmarks")]
 
-from chipbench import catalog, driver, refcore, tracing, traffic  # noqa: E402
+from chipbench import (  # noqa: E402
+    catalog, driver, refcore, streams, tracing, traffic)
 
 PEAKS = {"flops_per_s": 1e12, "bytes_per_s": 1e11}
 
 
 # ------------------------------------------------------------ discovery ----
+
+REFERENCE = ("make_params", "init_state", "to_store", "from_store", "step")
+WORK = ("weight_bytes", "state_bytes")  # besides the kind's own ``WORK``
+
+
+def kind_of(cfg: dict):
+    return catalog.stream_kind(cfg["dataset"].get("kind",
+                                                  catalog.DEFAULT_KIND))
+
 
 def test_every_name_resolves_to_its_files():
     bench = catalog.benchmark()
@@ -40,9 +50,14 @@ def test_every_name_resolves_to_its_files():
         assert (ROOT / c["file"]).resolve() == (
             catalog.HERE / "configs" / f"{c['name']}.json").resolve()
         assert cfg["name"] == c["name"]
-        assert cfg["reduced"] == c["reduced"] == []
+        assert cfg["reduced"] == c["reduced"]
+        assert all(isinstance(k, str) for k in c["reduced"])
+        kind = kind_of(cfg)
         ref, work = catalog.family(cfg["family"])
-        assert callable(ref.step) and callable(work.snapshot_flops)
+        for names, mod in ((streams.FUNCTIONS, kind), (REFERENCE, ref),
+                           (kind.WORK + WORK, work)):
+            for f in names:
+                assert callable(getattr(mod, f, None)), (mod.__name__, f)
     for w in bench["workloads"]:
         assert w["config"] in configs
         assert catalog.traffic(w["traffic"])["mode"] in driver.RUNNERS
@@ -65,8 +80,10 @@ def test_benchmark_json_keeps_the_contract_shape():
     assert 1 <= bench["run_seconds"] <= 51
     for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and 0 < len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
         assert name.match(w["name"]) and name.match(w["traffic"])
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 2)
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert name.match(m["name"]) and unit.match(m["unit"])
         assert m["better"] in ("lower", "higher")
@@ -86,20 +103,6 @@ def test_benchmark_json_keeps_the_contract_shape():
 
 DS = {"name": "tiny", "avg_nodes": 12, "avg_edges": 20, "max_nodes": 28,
       "max_edges": 48, "snapshots": 24, "global_nodes": 90}
-
-
-def test_graph_stream_is_deterministic_per_seed_and_seeds_differ():
-    a = traffic.graph_stream(DS, 2**31 + 5, 8, 4, (32, 128, 24))
-    b = traffic.graph_stream(DS, 2**31 + 5, 8, 4, (32, 128, 24))
-    c = traffic.graph_stream(DS, 2**31 + 6, 8, 4, (32, 128, 24))
-    for (s1, d1, e1), (s2, d2, e2) in zip(a[0], b[0]):
-        np.testing.assert_array_equal(s1, s2)
-        np.testing.assert_array_equal(d1, d2)
-        np.testing.assert_array_equal(e1, e2)
-    np.testing.assert_array_equal(a[1], b[1])
-    assert not all(s1.shape == s2.shape and (s1 == s2).all()
-                   for (s1, _, _), (s2, _, _) in zip(a[0], c[0]))
-    assert all(traffic.fits(s, 32, 128, 24) for s in a[0])
 
 
 def test_open_loop_keeps_the_amount_of_work_across_seeds():
@@ -256,6 +259,7 @@ def short_harness(monkeypatch):
 
 
 def run_tiny(cfg, mix, seconds=1.0, control=False, tmp=None):
+    """A tiny run; ``seconds=0`` serves exactly one replay pass."""
     rec, check, device = driver.run_cell(
         cfg, mix, 2**31 + 17, seconds, False, time.perf_counter(),
         tmp, peaks=PEAKS, control=control)
@@ -284,23 +288,58 @@ def test_the_control_at_the_cells_size_is_not_correct(name):
 
     cfg = catalog.config(name)
     ref, work = catalog.family(cfg["family"])
-    m, p = cfg["model"], cfg["plan"]
-    bucket = (p["n_pad"], p["e_pad"], p["k_max"])
-    snaps, feat, n_global = traffic.graph_stream(
-        cfg["dataset"], 2**31 + 29, m["in_dim"], m["edge_dim"], bucket)
-    stream = driver.Stream(snaps, work, m)
+    m, kind = cfg["model"], kind_of(cfg)
+    stream = driver.Stream(kind, kind.build(cfg, cfg["plan"], 2**31 + 29),
+                           work, m)
     params = jax.jit(lambda k: ref.make_params(k, m))(jax.random.key(29))
     idx = list(range(catalog.traffic("bcalpha-replay16")["history"]))
-    graphs = [refcore.prepare(*snaps[k], *bucket[:2], n_global) for k in idx]
-    outs, state = refcore.run_stream(ref, params, m, n_global, graphs, feat,
+    outs, state = refcore.run_stream(ref, params, m, stream.n_global,
+                                     [stream.prepare(k) for k in idx],
                                      "highest")
     sample = [(idx, [(o, 0.0) for o in outs], state)]
-    check = driver.compare(ref, params, m, n_global, stream, feat, bucket,
-                           sample, control=True)
+    check = driver.compare(ref, params, m, stream, sample, control=True)
     rec = driver.Record(mode="replay", peaks=PEAKS, attempted=len(idx))
     assert driver.verdict(rec, check, cfg["limits"]), check
     assert not driver.verdict(rec, driver.control_numbers(check),
                               cfg["limits"]), check
+
+
+def test_a_stream_kind_is_files_only(tmp_path, monkeypatch):
+    """A kind is one module found by name: a copy of ``snapshots`` under
+    another name, on the kinds' search path and named by the
+    configuration, runs a cell with no harness file edited, and its run
+    reads the same numbers as the ``snapshots`` kind's."""
+    shutil.copy(catalog.HERE / "streams" / "snapshots.py",
+                tmp_path / "snapshots_copy.py")
+    monkeypatch.setattr(streams, "__path__",
+                        [*streams.__path__, str(tmp_path)])
+    monkeypatch.delitem(sys.modules, "chipbench.streams.snapshots_copy",
+                        raising=False)
+    cfg, mix = tiny("gcrn", "replay")
+    copied = copy.deepcopy(cfg)
+    copied["dataset"]["kind"] = "snapshots_copy"
+    rec, check, ok = run_tiny(copied, mix, seconds=0.0, tmp=tmp_path)
+    used = sys.modules["chipbench.streams.snapshots_copy"]
+    assert Path(used.__file__).parent == tmp_path
+    assert ok, check
+    base, base_check, base_ok = run_tiny(cfg, mix, seconds=0.0, tmp=tmp_path)
+    assert base_ok and base_check == check
+    assert (rec.attempted, rec.live, rec.failed) == (
+        base.attempted, base.live, base.failed)
+
+
+def test_memory_peak_is_the_fullest_devices():
+    class Device:
+        def __init__(self, peak):
+            self.peak = peak
+
+        def memory_stats(self):
+            return None if self.peak is None else {
+                "peak_bytes_in_use": self.peak}
+
+    assert driver.memory_peak([Device(7)]) == 7
+    assert driver.memory_peak([Device(3), Device(9), Device(None),
+                               Device(5)]) == 9
 
 
 def _state_unchanged(orig):
